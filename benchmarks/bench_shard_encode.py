@@ -65,6 +65,9 @@ def _inner(channels: int, nb: int, n: int) -> None:
 
 def run(channels: int = 8, nb: int = 192, n: int = 32):
     env = dict(os.environ, PYTHONPATH="src")
+    # forced host devices by design: the child stays off any accelerator so
+    # it never contends with this process for the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={_DEVICES}")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_shard_encode", "--inner",

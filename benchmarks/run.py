@@ -79,6 +79,8 @@ def main(argv=None) -> None:
         args.quick = True
     if args.quick:
         os.environ["REPRO_BENCH_QUICK"] = "1"
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     modules = [
         ("table1", "bench_table1_ratio"),

@@ -109,4 +109,6 @@ if __name__ == "__main__":
     ap.add_argument("--tenants", type=int, default=4)
     ap.add_argument("--samples", type=int, default=32 * 512)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     asyncio.run(main(args))

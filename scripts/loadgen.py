@@ -286,6 +286,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.smoke:
         args.samples = min(args.samples, 4096)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     report = asyncio.run(run(args))
     if args.json:

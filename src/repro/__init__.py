@@ -33,6 +33,7 @@ _PUBLIC = {
     "ContainerFormatError": "repro.errors",
     "AutotuneCacheError": "repro.errors",
     "KernelShapeError": "repro.errors",
+    "DeviceBackendError": "repro.errors",
     "ApiError": "repro.errors",
     "AdmissionError": "repro.errors",
     "QuotaExceededError": "repro.errors",

@@ -23,10 +23,13 @@ Plans are backend-agnostic.  Three backends produce byte-identical output:
 Byte-exactness on an accelerator is *checked, never assumed*: the first
 time a (backend, mode, dtype, value_range, block_size) combination runs,
 a small probe plan is reconstructed on both paths and compared
-``tobytes()``-for-``tobytes()``.  If the device result differs (e.g. f64
-emulation on TPU) -- or the device path raises -- the engine logs the
-fallback once and routes that combination to the host path; the decision
-is observable via :func:`decode_stats` and pinned by tests.
+``tobytes()``-for-``tobytes()``.  A device result that differs is a routing
+fact: the engine logs it once and serves that combination from the host,
+counted as a ``fallback`` in :func:`decode_stats`.  A device path that
+*raises* is a fault, never a route: an explicit ``jax``/``pallas`` request
+(and an ``auto`` probe) raises :class:`~repro.errors.DeviceBackendError`.
+The TPU has no f64, so ``auto`` routes f64 plans to the host by rule --
+counted as ``host_routes`` -- and an explicit device backend refuses them.
 
 Device dispatch shapes are padded to powers of two (pad rows are zero-
 payload misses the per-block math ignores), so serving traffic reuses a
@@ -44,6 +47,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
+from ..errors import DeviceBackendError
 from .transforms import np_wrap_range
 
 __all__ = [
@@ -68,16 +72,19 @@ logger = logging.getLogger("repro.core.decode")
 # repro.obs registry (ISSUE 8) -- :func:`decode_stats` is a dict-shaped
 # compat view over them, byte-compatible with the pre-registry API that
 # tests pin.  ``fallbacks`` counts calls that *asked* for a device
-# backend but ran on the host because the probe failed (or the device
-# path raised); tests pin this so a silent fallback cannot masquerade as
-# device coverage.  ``autotune_probes``/``autotune_hits`` count measured
-# first-use probes vs cached ``"auto"`` resolutions.  Counters are
-# individually locked, so the pipelined service's worker-thread bumps
-# stay exact against the caller's reads.
+# backend but ran on the host because the exactness probe found the
+# backend not byte-exact here; ``host_routes`` counts ``auto`` calls the
+# engine routed to the host because the device has no exact form of the
+# plan's dtype (f64 on a TPU).  Tests pin both, so a host result cannot
+# masquerade as device coverage.  ``autotune_probes``/``autotune_hits``
+# count measured first-use probes vs cached ``"auto"`` resolutions.
+# Counters are individually locked, so the pipelined service's
+# worker-thread bumps stay exact against the caller's reads.
 _STAT_HELP = {
     "host_calls": "reconstruct calls served on the numpy host path",
     "device_calls": "reconstruct calls served on a device backend",
-    "fallbacks": "device requests that fell back to the host",
+    "fallbacks": "device requests served on the host: probe not exact",
+    "host_routes": "backend=auto calls routed to the host: no exact dtype",
     "autotune_probes": "backend=auto measured first-use probes",
     "autotune_hits": "backend=auto cached resolutions",
 }
@@ -370,9 +377,10 @@ def _device_fn(backend: str, mode: int, value_range):
 
 def _run_device(plan: DecodePlan, backend: str) -> np.ndarray:
     """Dispatch one plan on a device backend, padding shapes to powers of
-    two so serving traffic reuses compiled shapes.  f64 plans run under an
-    ``enable_x64`` scope (the encoder's f32 paths are unaffected)."""
-    from jax.experimental import enable_x64
+    two so serving traffic reuses compiled shapes.  Only f64 plans run with
+    64-bit mode on: under it every integer literal is int64, which Mosaic
+    cannot lower in a Pallas index map (and the TPU only emulates)."""
+    import jax
     dt = np.dtype(plan.dtype)
     nb, P = plan.nb, plan.payload_width
     nbp, nrp = _pow2(nb), _pow2(len(plan.payloads) + 1)
@@ -381,7 +389,7 @@ def _run_device(plan: DecodePlan, backend: str) -> np.ndarray:
     src = np.full(nbp, nrp - 1, dtype=np.int64)  # pads read the zero row
     src[:nb] = plan.src
     fn = _device_fn(backend, plan.mode, plan.value_range)
-    with enable_x64():
+    with jax.enable_x64(dt == np.float64):
         if plan.mode == MODE_STD:
             perm = np.broadcast_to(
                 np.arange(plan.block_size, dtype=np.int64),
@@ -428,10 +436,20 @@ def _probe_plan(mode: int, dtype, value_range, block_size: int,
                       block_idx=np.arange(nb, dtype=np.int64), seed=3)
 
 
+def _device_has_dtype(dtype) -> bool:
+    """Whether the default device computes ``dtype`` exactly.  The TPU has
+    no f64 (XLA emulates it, the Pallas kernels cannot compile it)."""
+    import jax
+
+    return np.dtype(dtype) != np.float64 or jax.default_backend() != "tpu"
+
+
 def _device_exact(backend: str, plan: DecodePlan) -> bool:
     """Probe (once per combination) whether ``backend`` reproduces the host
-    path byte-for-byte on this device.  A failed or crashing probe routes
-    the combination to the host path, with a single logged warning."""
+    path byte-for-byte on this device.  An inexact result is logged once
+    and routes the combination to the host.  A probe that raises is a
+    device fault: it is cached and re-raised as ``DeviceBackendError`` for
+    every later call of the combination, without touching the device."""
     key = (backend, plan.mode, np.dtype(plan.dtype).str, plan.value_range,
            plan.block_size)
     ok = _exact_cache.get(key)
@@ -441,17 +459,19 @@ def _device_exact(backend: str, plan: DecodePlan) -> bool:
         want = _reconstruct_numpy(probe)
         try:
             got = _run_device(probe, backend)
-            ok = got.tobytes() == want.tobytes()
-            if not ok:
-                logger.warning(
-                    "decode backend %r is not byte-exact on this device for "
-                    "%s; falling back to host reconstruction", backend, key)
         except Exception as e:
-            ok = False
+            ok = _exact_cache[key] = DeviceBackendError(
+                f"decode backend {backend!r} failed its probe on this "
+                f"device for {key}: {e}")
+            raise ok from e
+        ok = got.tobytes() == want.tobytes()
+        if not ok:
             logger.warning(
-                "decode backend %r failed on this device for %s (%s); "
-                "falling back to host reconstruction", backend, key, e)
+                "decode backend %r is not byte-exact on this device for "
+                "%s; routing it to host reconstruction", backend, key)
         _exact_cache[key] = ok
+    if isinstance(ok, DeviceBackendError):
+        raise ok
     return ok
 
 
@@ -542,13 +562,8 @@ def _probe_autotune(mode: int, dtype, value_range, block_size: int,
 
     times = {"numpy": best_of(lambda: _reconstruct_numpy(plan))}
     for b in BACKENDS[1:]:
-        if not _device_exact(b, plan):
-            continue
-        try:
+        if _device_exact(b, plan):
             times[b] = best_of(lambda: _run_device(plan, b))
-        except Exception as e:
-            logger.warning("autotune probe for backend %r failed (%s); "
-                           "excluding it", b, e)
     # the host path wins ties: a device must be >5% faster on the probe to
     # take the route (noise margin; a near-tie is not worth the dispatch)
     backend = min(sorted(times), key=times.get)
@@ -593,12 +608,18 @@ def reconstruct(plan: DecodePlan, backend: str = "numpy") -> np.ndarray:
     """Rebuild ``(nb, B)`` block values from a plan (paper Sec. V-A2/V-B2).
 
     ``backend`` is ``"numpy"`` (host reference), ``"jax"``/``"pallas"``
-    (device; byte-identical, auto-falling back to host -- logged and
-    counted in :func:`decode_stats` -- when the exactness probe fails on
-    the current device), or ``"auto"`` (the measured-best backend for the
-    plan's (mode, dtype, size bucket) -- :func:`resolve_backend`).
-    Purely per-block math: callers may stack many ranges into one padded
-    plan (:func:`pad_parts`) and slice the result apart.
+    (device; byte-identical, served from the host -- logged and counted as
+    a fallback in :func:`decode_stats` -- when the exactness probe finds
+    the backend inexact on the current device), or ``"auto"`` (the
+    measured-best backend for the plan's (mode, dtype, size bucket) --
+    :func:`resolve_backend`; dtypes the device cannot compute exactly go
+    to the host, counted as ``host_routes``).  A device backend that
+    raises -- at its probe or at this dispatch -- or an explicit device
+    backend asked for a dtype the device lacks raises
+    :class:`~repro.errors.DeviceBackendError`; no device failure is ever
+    served from the host.  Purely per-block math: callers may stack many
+    ranges into one padded plan (:func:`pad_parts`) and slice the result
+    apart.
     """
     if plan.nb == 0:
         # validate the name, but never autotune-probe for an empty plan
@@ -606,6 +627,14 @@ def reconstruct(plan: DecodePlan, backend: str = "numpy") -> np.ndarray:
             raise ValueError(f"unknown decode backend {backend!r}; "
                              f"expected one of {BACKENDS + ('auto',)}")
         return np.zeros((0, plan.block_size), dtype=np.dtype(plan.dtype))
+    if backend != "numpy" and not _device_has_dtype(plan.dtype):
+        if backend != "auto":
+            raise DeviceBackendError(
+                f"decode backend {backend!r} has no exact "
+                f"{np.dtype(plan.dtype)} on this device; use 'numpy' or "
+                f"'auto'")
+        backend = "numpy"
+        _bump("host_routes")
     backend = resolve_backend(backend, plan.mode, plan.dtype, plan.nb,
                               plan.value_range, plan.block_size)
     _backend_counters[backend].inc()
@@ -615,15 +644,12 @@ def reconstruct(plan: DecodePlan, backend: str = "numpy") -> np.ndarray:
                 out = _run_device(plan, backend)
             except Exception as e:
                 # the probe passed but THIS shape failed (device OOM,
-                # shape-specific compile error): serve the call from the
-                # host instead of failing it
-                logger.warning(
-                    "decode backend %r failed at dispatch (nb=%d): %s; "
-                    "serving this call from the host path",
-                    backend, plan.nb, e)
-            else:
-                _bump("device_calls")
-                return out
+                # shape-specific compile error): a fault, not a route
+                raise DeviceBackendError(
+                    f"decode backend {backend!r} failed at dispatch "
+                    f"(nb={plan.nb}): {e}") from e
+            _bump("device_calls")
+            return out
         _bump("fallbacks")
     _bump("host_calls")
     return _reconstruct_numpy(plan)

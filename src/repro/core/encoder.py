@@ -305,7 +305,6 @@ def _step_fused(tile_d: int, params: EncoderParams, num_dict: int,
     ``_step`` it consumes pre-sorted rows from the batched sort stage."""
     from repro.kernels.encode_step import (DEC_COUNT, DEC_HIT, DEC_OVER,
                                            DEC_SLOT, encode_step_pallas)
-    from repro.kernels.ops import _INTERPRET
 
     block, xs, valid = blk
     if params.error_bound is None:
@@ -313,14 +312,14 @@ def _step_fused(tile_d: int, params: EncoderParams, num_dict: int,
             xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
             state.count, valid, d_crit=params.d_crit, rel_tol=params.rel_tol,
             use_minmax=params.use_minmax, use_ks=params.use_ks,
-            num_dict=num_dict, tile_d=tile_d, interpret=_INTERPRET)
+            num_dict=num_dict, tile_d=tile_d)
         new_raw = state.raw_blocks
     else:
         new_sorted, ndmin, ndmax, nvalid, new_raw, dec = encode_step_pallas(
             xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
             state.count, valid, d_crit=params.d_crit, rel_tol=params.rel_tol,
             use_minmax=params.use_minmax, use_ks=params.use_ks,
-            num_dict=num_dict, tile_d=tile_d, interpret=_INTERPRET,
+            num_dict=num_dict, tile_d=tile_d,
             raw=block, raw_blocks=state.raw_blocks,
             error_bound=params.error_bound,
             error_cumulative=params.error_cumulative)
@@ -381,7 +380,7 @@ def _encode_scan():
 
 MATCHERS = ("reference", "ops", "fused")
 ENCODE_AUTOTUNE_VERSION = 1
-_FUSED_TILE_SWEEP = (8, 32, 128)
+_FUSED_TILE_SWEEP = (8, 32)  # tile sizes that compile for v5e at n <= 256
 _PROBE_BLOCKS = 8
 
 _TUNER = MeasuredTuner(
@@ -440,8 +439,9 @@ def _named_matcher(name: str, tile_d: Optional[int] = None):
 
 def _probe_matcher(num_dict: int, n: int, dtype) -> dict:
     """Time each matcher on a short probe scan at the real (D, n, dtype)
-    operating point.  A candidate that fails to run (e.g. a tile size too
-    large for device memory) is excluded, not fatal."""
+    operating point.  Every candidate must run: a kernel that fails to
+    compile raises here instead of quietly giving way to the jnp
+    reference (the tile sweep holds only sizes that compile for v5e)."""
     import numpy as np
 
     rng = np.random.default_rng(0)
@@ -459,11 +459,7 @@ def _probe_matcher(num_dict: int, n: int, dtype) -> dict:
     candidates = [("ops", _named_matcher("ops"))]
     candidates += [(f"fused/{td}", ("fused", td)) for td in _FUSED_TILE_SWEEP]
     for label, m in candidates:
-        try:
-            times[label] = best_of(lambda m=m: run(m))
-        except Exception as e:
-            logger.warning("matcher probe %r failed (%s); excluding it",
-                           label, e)
+        times[label] = best_of(lambda m=m: run(m))
     winner = min(sorted(times), key=times.get)
     if winner.startswith("fused/"):
         name, tile_d = "fused", int(winner.split("/")[1])
@@ -682,13 +678,11 @@ def _step_mixed_fused(tile_d: int, params: EncoderParams, num_dict: int,
     covers the whole heterogeneous step."""
     from repro.kernels.encode_step import (DEC_COUNT, DEC_HIT, DEC_OVER,
                                            DEC_SLOT, encode_step_pallas)
-    from repro.kernels.ops import _INTERPRET
 
     block, xs, valid = blk
     kw = dict(d_crit=0.0, rel_tol=params.rel_tol,  # d_crit from chan
               use_minmax=params.use_minmax, use_ks=params.use_ks,
-              num_dict=num_dict, tile_d=tile_d, interpret=_INTERPRET,
-              chan=chan_arr)
+              num_dict=num_dict, tile_d=tile_d, chan=chan_arr)
     if params.error_bound is None:
         new_sorted, ndmin, ndmax, nvalid, dec = encode_step_pallas(
             xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
@@ -844,7 +838,6 @@ def encode_decisions_mixed(
 def _mixed_sharded_scan(mesh, axis_name: str):
     """shard_map'd mixed scan: channel axis split over the mesh like
     ``_sharded_scan``, with the ChanParams arrays sharded alongside."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
@@ -872,11 +865,11 @@ def _mixed_sharded_scan(mesh, axis_name: str):
             x = jnp.sort(b, axis=-1)
             return jax.vmap(one)(s, b, x, v, cp)
 
-        return shard_map(
+        return jax.shard_map(
             shard, mesh=mesh,
             in_specs=(st_spec, blk_spec, msk_spec, chan_spec),
             out_specs=(out_spec, st_spec),
-            check_rep=False,
+            check_vma=False,
         )(state, blocks, valid, chan)
 
     return scan
@@ -959,7 +952,6 @@ def _sharded_scan(mesh, axis_name: str):
 
     The per-channel carry lives sharded on its device between calls and is
     donated like the single-device path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
@@ -998,13 +990,13 @@ def _sharded_scan(mesh, axis_name: str):
 
             return jax.vmap(one)(s, b, x, v)
 
-        # check_rep=False: the pallas matcher has no replication rule; all
+        # check_vma=False: the pallas matcher has no replication rule; all
         # operands map over the channel axis anyway (no replicated outputs).
-        return shard_map(
+        return jax.shard_map(
             shard, mesh=mesh,
             in_specs=(st_spec, blk_spec, msk_spec),
             out_specs=(out_spec, st_spec),
-            check_rep=False,
+            check_vma=False,
         )(state, blocks, valid)
 
     return scan
@@ -1144,7 +1136,6 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
     of every channel are split over the ``dict_axis`` devices, with the
     per-step best-match arg-min all-reduced across them.  A 1-sized channel
     axis gives pure D-sharding of fat channels."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
@@ -1152,7 +1143,7 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
     blk_spec = P(ch_axis, None, None)
     msk_spec = P(ch_axis, None)
     # decisions come out identical on every dict shard (post-pmin); declare
-    # them replicated over dict_axis (check_rep=False skips verification,
+    # them replicated over dict_axis (check_vma=False skips verification,
     # as for the channel-sharded scan's pallas matcher)
     out_spec = (P(ch_axis, None),) * 3
 
@@ -1195,11 +1186,11 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
 
             return jax.vmap(one)(s, b, x, v)
 
-        out, new_p = shard_map(
+        out, new_p = jax.shard_map(
             shard, mesh=mesh,
             in_specs=(st_spec, blk_spec, msk_spec),
             out_specs=(out_spec, st_spec),
-            check_rep=False,
+            check_vma=False,
         )(pstate, blocks, valid)
         new_state = DictState(
             sorted_blocks=new_p.sorted_blocks[:, :num_dict],

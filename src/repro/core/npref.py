@@ -107,9 +107,14 @@ def encode_decisions_np(
     slot = np.zeros(nb, dtype=np.int32)
     overwrite = np.zeros(nb, dtype=bool)
     misses = {"cold": 0, "minmax": 0, "ks": 0, "error_bound": 0}
+    # eq. 3 is evaluated in float32, as every device matcher does: the
+    # extremes, w = dmax - dmin, t = w * r and dmin +- t all round to f32,
+    # so a block whose extreme sits on a rounded bound decides alike
+    r32 = np.float32(rel_tol)
     for i in range(nb):
         x = blocks[i]
         xmin, xmax = float(np.min(x)), float(np.max(x))
+        xmin32, xmax32 = np.float32(xmin), np.float32(xmax)
         hit = -1
         # deepest gate any entry got past, for miss attribution (0 = no
         # valid entry, 1 = min/max, 2 = KS, 3 = error bound)
@@ -119,11 +124,11 @@ def encode_decisions_np(
                 continue
             depth = max(depth, 1)
             if use_minmax:
-                w = dmax[s] - dmin[s]
-                t = w * rel_tol
+                lo, hi = np.float32(dmin[s]), np.float32(dmax[s])
+                t = (hi - lo) * r32
                 if not (
-                    dmin[s] - t <= xmin <= dmin[s] + t
-                    and dmax[s] - t <= xmax <= dmax[s] + t
+                    lo - t <= xmin32 <= lo + t
+                    and hi - t <= xmax32 <= hi + t
                 ):
                     continue
             depth = max(depth, 2)

@@ -639,8 +639,9 @@ def decode_stream(data: bytes, seed: int = 0,
     are random permutations of that block, res/delta hits re-anchor the
     stored transformed values on the hit's own base.  ``backend`` selects
     the reconstruction backend (``repro.core.decode.BACKENDS``); every
-    backend is byte-identical (device backends fall back to the host when
-    the exactness probe fails -- logged).
+    backend is byte-identical (a device backend the exactness probe finds
+    inexact is routed to the host -- logged and counted; one that raises
+    raises ``DeviceBackendError``).
 
     Note: each hit's permutation is drawn statelessly from ``(seed, block
     position)`` (``decode.hit_perms``), so the sampled permutations differ

@@ -30,6 +30,7 @@ __all__ = [
     "ContainerFormatError",
     "AutotuneCacheError",
     "KernelShapeError",
+    "DeviceBackendError",
     "ApiError",
     "AdmissionError",
     "QuotaExceededError",
@@ -98,6 +99,16 @@ class KernelShapeError(ReproError, ValueError):
     http_status = 500
 
 
+class DeviceBackendError(ReproError, RuntimeError):
+    """An explicitly requested device backend cannot serve the call on this
+    device: its first probe or its dispatch raised, or the plan's dtype has
+    no exact form there (f64 on a TPU).  Raised instead of silently serving
+    the call from the host, so device coverage can never be faked."""
+
+    code = "device_backend"
+    http_status = 500
+
+
 # ------------------------------------------------------------ serving layer
 class ApiError(ReproError, ValueError):
     """A request payload failed validation (bad JSON, missing field,
@@ -160,7 +171,8 @@ class OverloadedError(AdmissionError):
 ERROR_CODES: Dict[str, Type[ReproError]] = {
     cls.code: cls
     for cls in (ReproError, StreamFormatError, ContainerFormatError,
-                AutotuneCacheError, KernelShapeError, ApiError,
+                AutotuneCacheError, KernelShapeError, DeviceBackendError,
+                ApiError,
                 NotFoundError, AdmissionError, QuotaExceededError,
                 RateLimitedError, OverloadedError)
 }

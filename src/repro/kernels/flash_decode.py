@@ -8,16 +8,17 @@ in-kernel by grouping query heads over each kv head (no materialized
 repeat_kv).  Grid: (batch, C/chunk), cache-chunk minor so scratch carries
 across the chunk sweep; the last chunk step finalizes o = acc / l.
 
-Validated in interpret mode against ``ref.flash_decode_ref``.
+Validated against ``ref.flash_decode_ref`` (interpreted on the CPU; see
+``kernels.platform``).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .platform import pallas_call
 
 CHUNK_C = 512
 
@@ -62,8 +63,8 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr, acc_scr):
                     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def flash_decode_pallas(q, k_cache, v_cache, valid, *, interpret: bool = True):
+@jax.jit
+def flash_decode_pallas(q, k_cache, v_cache, valid):
     """q: (B, H, hd) pre-scaled query; k/v_cache: (B, C, Hkv, hd);
     valid: (B, C) bool (ring-position validity incl. window masking).
     Returns (B, H, hd) f32."""
@@ -73,7 +74,7 @@ def flash_decode_pallas(q, k_cache, v_cache, valid, *, interpret: bool = True):
     chunk = min(CHUNK_C, C)
     assert C % chunk == 0, "cache length must be a multiple of the chunk"
     qg = q.reshape(B, Hkv, G, hd)
-    out = pl.pallas_call(
+    out = pallas_call(
         _kernel,
         grid=(B, C // chunk),
         in_specs=[
@@ -89,6 +90,5 @@ def flash_decode_pallas(q, k_cache, v_cache, valid, *, interpret: bool = True):
             pltpu.VMEM((Hkv, G), jnp.float32),      # running denominator
             pltpu.VMEM((Hkv, G, hd), jnp.float32),  # output accumulator
         ],
-        interpret=interpret,
     )(qg, k_cache, v_cache, valid)
     return out.reshape(B, H, hd)

@@ -20,9 +20,6 @@ from .ref import dict_match_ref
 
 __all__ = ["dict_match", "dict_match_ks", "dict_match_reference"]
 
-# On CPU we must run the kernel in interpret mode; on TPU compile for real.
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 @functools.partial(jax.jit, static_argnames=("rel_tol", "tile_d"))
 def dict_match(xs_sorted, dict_blocks, dmin, dmax, rel_tol: float = 0.1,
@@ -35,7 +32,7 @@ def dict_match(xs_sorted, dict_blocks, dmin, dmax, rel_tol: float = 0.1,
         dmin = jnp.pad(dmin, (0, pad))
         dmax = jnp.pad(dmax, (0, pad))
     ks, mm = dict_match_pallas(xs_sorted, dict_blocks, dmin, dmax, rel_tol,
-                               interpret=_INTERPRET, tile_d=tile_d)
+                               tile_d=tile_d)
     return ks[:num_d], mm[:num_d]
 
 

@@ -8,15 +8,17 @@ blocks (measured, see tests/test_decode_backends.py).  Byte-identity
 between the host and device decode paths therefore needs a cumsum that
 accumulates in the SAME sequential order -- this kernel.
 
-One program per tile of TILE_R rows; within the tile a ``fori_loop`` walks
-the P columns carrying the running sum, exactly like ``np.add.accumulate``.
+One program per tile of TILE_R rows; within the tile a static column walk
+carries the running sum, exactly like ``np.add.accumulate`` (Mosaic has no
+cumsum lowering and no dynamic lane slicing, so the walk is unrolled).
 Column 0 is stored as-is (``acc = x[:, 0]``, not ``0 + x[:, 0]``) so a
 leading ``-0.0`` survives bit-for-bit.  P is small (block_size - 1 <= 254)
 so the serialized column walk costs nothing against the gather around it.
 
-On CPU the kernel runs in interpret mode (like ``dict_match``); on TPU f64
-is unsupported and the caller's exactness probe (repro.core.decode) falls
-back to the host path instead.
+Like every kernel here it is compiled for the platform it is lowered for
+and interpreted only on the CPU (``kernels.platform``).  The TPU has no
+f64, so ``repro.core.decode`` routes f64 plans to the host: that is a
+routing rule counted in ``decode_stats()``, not a fallback on error.
 """
 from __future__ import annotations
 
@@ -26,42 +28,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 TILE_R = 8
 
 __all__ = ["seq_cumsum_pallas", "seq_cumsum", "TILE_R"]
 
-# On CPU we must run the kernel in interpret mode; on TPU compile for real.
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 def _seq_cumsum_kernel(x_ref, o_ref):
-    P = x_ref.shape[1]
-    acc = x_ref[:, 0]
-    pl.store(o_ref, (slice(None), pl.dslice(0, 1)), acc[:, None])
-
-    def body(j, acc):
-        v = pl.load(x_ref, (slice(None), pl.dslice(j, 1)))[:, 0]
-        acc = acc + v
-        pl.store(o_ref, (slice(None), pl.dslice(j, 1)), acc[:, None])
-        return acc
-
-    jax.lax.fori_loop(1, P, body, acc)
+    acc = x_ref[:, 0:1]
+    o_ref[:, 0:1] = acc
+    for j in range(1, x_ref.shape[1]):
+        acc = acc + x_ref[:, j:j + 1]
+        o_ref[:, j:j + 1] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def seq_cumsum_pallas(x, interpret: bool = True):
+@jax.jit
+def seq_cumsum_pallas(x):
     """x (R, P) -> row-wise cumulative sum, accumulated strictly
     left-to-right (bit-identical to ``np.cumsum(x, axis=1)``).  R must be
     a multiple of TILE_R (use ``seq_cumsum`` for arbitrary R)."""
     R, P = x.shape
-    assert R % TILE_R == 0, "pad R to a TILE_R multiple (see seq_cumsum)"
-    return pl.pallas_call(
+    if R % TILE_R:
+        raise ValueError(f"R={R} is not a multiple of TILE_R={TILE_R}; "
+                         f"use seq_cumsum, which pads")
+    return pallas_call(
         _seq_cumsum_kernel,
         grid=(R // TILE_R,),
         in_specs=[pl.BlockSpec((TILE_R, P), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((TILE_R, P), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, P), x.dtype),
-        interpret=interpret,
     )(x)
 
 
@@ -72,4 +68,4 @@ def seq_cumsum(x):
     pad = (-R) % TILE_R
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    return seq_cumsum_pallas(x, interpret=_INTERPRET)[:R]
+    return seq_cumsum_pallas(x)[:R]

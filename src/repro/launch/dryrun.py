@@ -139,14 +139,6 @@ def parse_collectives(hlo_text: str) -> Dict[str, Dict[str, float]]:
 # ------------------------------------------------------------------- lowering
 
 
-def _mesh_context(mesh):
-    """``jax.sharding.set_mesh`` landed after the 0.4.x line; older releases
-    spell it ``use_mesh`` or rely on ``Mesh`` being a context manager."""
-    setter = getattr(jax.sharding, "set_mesh", None) or getattr(
-        jax.sharding, "use_mesh", None)
-    return setter(mesh) if setter is not None else mesh
-
-
 def build_cell(arch: str, shape_name: str, multi_pod: bool,
                microbatches: int = 0, cfg_override=None, smoke: bool = False):
     """Returns (lowered, meta) for one cell.  smoke=True swaps in the
@@ -174,7 +166,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         step = make_train_step(cfg, lr=1e-4, microbatches=mb)
         fn = jax.jit(step, in_shardings=(state_sh, batch_sh),
                      out_shardings=(state_sh, None), donate_argnums=(0,))
-        with _mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = fn.lower(state_shape, specs)
     elif shape.kind == "prefill":
         params_shape = jax.eval_shape(
@@ -196,7 +188,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         else:
             fn = jax.jit(pf, in_shardings=(p_sh, batch_sh["tokens"]))
             args = (params_shape, specs["tokens"])
-        with _mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = fn.lower(*args)
     else:  # decode
         params_shape = jax.eval_shape(
@@ -215,7 +207,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
 
         fn = jax.jit(ds, in_shardings=(p_sh, cache_sh, batch_sh["tokens"]),
                      out_shardings=(None, cache_sh), donate_argnums=(1,))
-        with _mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = fn.lower(params_shape, cache_shape,
                                specs["tokens"])
     set_sharding_rules(None)

@@ -23,7 +23,7 @@ if __name__ == "__main__":  # own the device-count flag (precedes jax import)
         (os.environ.get("XLA_FLAGS", "") + " " + _flag).strip())
 
 import json
-from typing import List
+from typing import List, Tuple
 
 import numpy as np
 
@@ -49,17 +49,35 @@ def _signal(n: int, vr, seed: int) -> np.ndarray:
     return x
 
 
-def _session_blobs(codec, chans, plan) -> List[bytes]:
+def _carry_devices(state) -> int:
+    """Devices that hold a shard of an encode carry: what the plan really
+    spread, not what its mesh promised."""
+    import jax
+
+    return len(set().union(*(leaf.sharding.device_set
+                             for leaf in jax.tree.leaves(state))))
+
+
+def _session_blobs(codec, chans, plan) -> Tuple[List[bytes], int]:
+    """Per-channel stream bytes of a two-feed session, and the devices its
+    carry spanned."""
     C = chans.shape[0]
     s = codec.session(channels=C, plan=plan)
-    parts = [s.feed(chans[:, :517]), s.feed(chans[:, 517:]), s.finish()]
-    return [b"".join(p[ci] for p in parts) for ci in range(C)]
+    parts = [s.feed(chans[:, :517]), s.feed(chans[:, 517:])]
+    spanned = _carry_devices(s._dev_state)
+    parts.append(s.finish())
+    return [b"".join(p[ci] for p in parts) for ci in range(C)], spanned
 
 
 def run_check(backend: str = "jax", channels: int = 5,
-              samples: int = 16 * 80 + 7, dict_shards: int = 0) -> dict:
+              samples: int = 16 * 80 + 7, dict_shards: int = 0,
+              cases=CASES, block_size: int = 16,
+              alpha: float = 0.05) -> dict:
     """``dict_shards=0`` shards the dictionary over all devices (D-axis
-    case) in addition to the channel-sharded cases; ``1`` disables it."""
+    case) in addition to the channel-sharded cases; ``1`` disables it.
+    ``cases`` are ``(mode, num_dict, value_range)``.  The record's
+    ``spans`` lists, per checked path, how many devices its encode carry
+    was spread over."""
     import jax
 
     from repro.core import IdealemCodec
@@ -73,19 +91,22 @@ def run_check(backend: str = "jax", channels: int = 5,
                 "expected": want}
     if dict_shards == 0:
         dict_shards = n_dev
-    checked = []
-    for mode, num_dict, vr in CASES:
-        codec = IdealemCodec(mode=mode, block_size=16, num_dict=num_dict,
-                             alpha=0.05, rel_tol=0.5, value_range=vr,
+    B = block_size
+    checked, spans = [], {}
+    for mode, num_dict, vr in cases:
+        case = f"{mode}/D{num_dict}"
+        codec = IdealemCodec(mode=mode, block_size=B, num_dict=num_dict,
+                             alpha=alpha, rel_tol=0.5, value_range=vr,
                              backend=backend)
         chans = np.stack([_signal(samples, vr, seed=11 + ci)
                           for ci in range(channels)])
-        plan = make_encode_plan(channels, block_size=16)
+        plan = make_encode_plan(channels, block_size=B)
         assert plan.num_devices == min(n_dev, channels), plan.summary()
 
         # sharded session bytes == single-device session bytes
-        single = _session_blobs(codec, chans, plan=None)
-        sharded = _session_blobs(codec, chans, plan=plan)
+        single, _ = _session_blobs(codec, chans, plan=None)
+        sharded, spans[f"{case}/session"] = _session_blobs(codec, chans,
+                                                           plan=plan)
         if single != sharded:
             return {"status": "mismatch", "where": "session",
                     "mode": mode, "num_dict": num_dict}
@@ -95,19 +116,20 @@ def run_check(backend: str = "jax", channels: int = 5,
         # per-step best match all-reduced (one channel group: the fat-
         # channel scale-out the channel-sharded path cannot provide)
         if dict_shards > 1:
-            dplan = make_encode_plan(channels, block_size=16,
+            dplan = make_encode_plan(channels, block_size=B,
                                      dict_shards=dict_shards)
             assert dplan.dict_shards == dict_shards, dplan.summary()
-            dsharded = _session_blobs(codec, chans, plan=dplan)
+            dsharded, spans[f"{case}/session_dshard"] = _session_blobs(
+                codec, chans, plan=dplan)
             if single != dsharded:
                 return {"status": "mismatch", "where": "session_dshard",
                         "mode": mode, "num_dict": num_dict}
 
         # coalesced ragged streams decode like one-shot per-stream encode
-        cplan = make_encode_plan(-(-channels // n_dev) * n_dev, block_size=16)
+        cplan = make_encode_plan(-(-channels // n_dev) * n_dev, block_size=B)
         co = StreamCoalescer(policy=FlushPolicy(max_batch_blocks=64),
-                             plan=cplan, mode=mode, block_size=16,
-                             num_dict=num_dict, alpha=0.05, rel_tol=0.5,
+                             plan=cplan, mode=mode, block_size=B,
+                             num_dict=num_dict, alpha=alpha, rel_tol=0.5,
                              value_range=vr, backend=backend)
         segs = {ci: [] for ci in range(channels)}
         for ci in range(channels):
@@ -122,6 +144,7 @@ def run_check(backend: str = "jax", channels: int = 5,
                     if res:
                         for k, v in res.items():
                             segs[int(k)].append(v)
+        spans[f"{case}/coalescer"] = _carry_devices(co._state)
         for ci in range(channels):
             segs[ci].append(co.close_stream(str(ci)))
         for ci in range(channels):
@@ -130,9 +153,9 @@ def run_check(backend: str = "jax", channels: int = 5,
             if not np.array_equal(got, ref):
                 return {"status": "mismatch", "where": "coalescer",
                         "mode": mode, "num_dict": num_dict, "channel": ci}
-        checked.append(f"{mode}/D{num_dict}")
+        checked.append(case)
     return {"status": "ok", "devices": n_dev, "backend": backend,
-            "cases": checked}
+            "cases": checked, "spans": spans}
 
 
 def main() -> None:

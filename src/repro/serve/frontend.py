@@ -16,7 +16,8 @@ independent ``CompressRequest``, failures carried per line as protocol
 error documents while the neighbours proceed.  The tenant is the
 ``x-tenant`` header.  Routes:
 
-  POST /v1/open     {"stream_id", "config"?: CodecConfig, "coalesce"?: bool}
+  POST /v1/open     {"stream_id", "config"?: CodecConfig, "coalesce"?: bool,
+                     "dtype"?: "float64" | "float32"}
   POST /v1/feed     CompressRequest            (JSON-lines batchable)
   POST /v1/close    {"stream_id"}           -> final FeedResult
   POST /v1/collect  {"stream_id"}           -> FeedResult (buffered segs)
@@ -464,7 +465,7 @@ class ServeFrontend:
         if path == "/v1/open":
             if not isinstance(doc, dict):
                 raise ApiError("open: expected object")
-            extra = set(doc) - {"stream_id", "config", "coalesce"}
+            extra = set(doc) - {"stream_id", "config", "coalesce", "dtype"}
             if extra:
                 raise ApiError(f"open: unknown field(s) {sorted(extra)}")
             sid = doc.get("stream_id")
@@ -472,10 +473,10 @@ class ServeFrontend:
                 raise ApiError("open: stream_id must be a non-empty string")
             cfg = (self.default_config if doc.get("config") is None
                    else api.CodecConfig.from_json(doc["config"]))
-            tenant.open_stream(sid, cfg, coalesce=bool(doc.get("coalesce",
-                                                               False)))
-            return {"stream_id": sid, "coalesce": bool(doc.get("coalesce",
-                                                               False)),
+            coalesce = bool(doc.get("coalesce", False))
+            dtype = doc.get("dtype", "float64")
+            tenant.open_stream(sid, cfg, coalesce=coalesce, dtype=dtype)
+            return {"stream_id": sid, "coalesce": coalesce, "dtype": dtype,
                     "config": cfg.to_json()}
         if path == "/v1/feed":
             req = api.CompressRequest.from_json(doc)
@@ -645,8 +646,8 @@ class FrontendClient:
     # ------------------------------------------------------------ verb sugar
     async def open(self, stream_id: str,
                    config: Optional[api.CodecConfig] = None,
-                   coalesce: bool = False) -> dict:
-        doc = {"stream_id": stream_id, "coalesce": coalesce}
+                   coalesce: bool = False, dtype: str = "float64") -> dict:
+        doc = {"stream_id": stream_id, "coalesce": coalesce, "dtype": dtype}
         if config is not None:
             doc["config"] = config.to_json()
         return await self.post("/v1/open", doc)

@@ -25,6 +25,10 @@ Streams come in two service shapes, chosen at open:
   (or the front end's deadline tick) cuts one padded device batch for the
   whole cohort.  Segments produced by a background flush buffer on the
   stream until the client's next call collects them.
+
+Either shape stores samples as ``float64`` unless the open asks for
+``float32`` (a micro-PMU's native width, and the only float width a TPU
+decodes on the device).
 """
 from __future__ import annotations
 
@@ -39,7 +43,10 @@ from repro.errors import (ApiError, NotFoundError, QuotaExceededError,
                           RateLimitedError)
 
 __all__ = ["TenantQuota", "TokenBucket", "TenantStream", "Tenant",
-           "TenantRegistry"]
+           "TenantRegistry", "STREAM_DTYPES"]
+
+# sample widths a wire stream may be stored at (``/v1/open`` "dtype")
+STREAM_DTYPES = ("float64", "float32")
 
 
 @dataclass(frozen=True)
@@ -122,6 +129,7 @@ class TenantStream:
     config: api.CodecConfig
     coalesced: bool
     session: object = None               # IdealemSession (direct streams)
+    dtype: str = "float64"               # stored sample width
     pending_segments: List[bytes] = field(default_factory=list)
     # cumulative stat snapshot at the last feed, for per-call deltas
     last_stats: tuple = (0, 0, 0, 0)     # blocks, hits, bytes_in, bytes_out
@@ -148,8 +156,9 @@ class Tenant:
         self._clock = clock if clock is not None else time.monotonic
         self.policy = policy if policy is not None else FlushPolicy()
         self.streams: Dict[str, TenantStream] = {}
-        # one coalescer per codec config (a cohort shares one scan shape)
-        self.coalescers: Dict[api.CodecConfig, object] = {}
+        # one coalescer per (codec config, sample dtype): a cohort shares
+        # one scan shape and one stream format
+        self.coalescers: Dict[tuple, object] = {}
         self._decomp = None
         self._decode_backend = decode_backend
         self.bucket = (TokenBucket(quota.max_bytes_per_s, quota.burst_bytes,
@@ -192,28 +201,32 @@ class Tenant:
 
     # ------------------------------------------------------------ lifecycle
     def open_stream(self, stream_id: str, config: api.CodecConfig,
-                    coalesce: bool = False) -> TenantStream:
+                    coalesce: bool = False,
+                    dtype: str = "float64") -> TenantStream:
         from repro.core import IdealemCodec
         if stream_id in self.streams:
             raise ApiError(f"stream {stream_id!r} already open")
+        if dtype not in STREAM_DTYPES:
+            raise ApiError(f"open: dtype must be one of {STREAM_DTYPES}, "
+                           f"got {dtype!r}")
         self.admit_open_stream()
         if coalesce:
             if config.backend == "numpy":
                 raise ApiError("coalesced streams batch on a device "
                                "backend; open with coalesce=false or a "
                                "jax/pallas config")
-            coal = self.coalescers.get(config)
+            coal = self.coalescers.get((config, dtype))
             if coal is None:
                 from .compress import StreamCoalescer
-                coal = StreamCoalescer(policy=self.policy,
+                coal = StreamCoalescer(policy=self.policy, dtype=dtype,
                                        clock=self._clock, **config.kwargs())
-                self.coalescers[config] = coal
+                self.coalescers[(config, dtype)] = coal
             coal.open_stream(stream_id)
-            st = TenantStream(stream_id, config, coalesced=True)
+            st = TenantStream(stream_id, config, coalesced=True, dtype=dtype)
         else:
             codec = IdealemCodec.from_config(config)
             st = TenantStream(stream_id, config, coalesced=False,
-                              session=codec.session())
+                              session=codec.session(dtype=dtype), dtype=dtype)
         self.streams[stream_id] = st
         return st
 
@@ -231,7 +244,7 @@ class Tenant:
         arr = np.asarray(req.samples)
         self.admit_bytes(arr.nbytes)
         if st.coalesced:
-            coal = self.coalescers[st.config]
+            coal = self.coalescers[(st.config, st.dtype)]
             staged = coal.staged_samples(req.stream_id)
             B = coal.block_size
             self.admit_staged((staged + len(arr)) // B - staged // B)
@@ -245,7 +258,7 @@ class Tenant:
     def close_stream(self, stream_id: str) -> api.FeedResult:
         st = self.stream(stream_id)
         if st.coalesced:
-            coal = self.coalescers[st.config]
+            coal = self.coalescers[(st.config, st.dtype)]
             seg = st.collect() + coal.close_stream(stream_id)
         else:
             seg = st.collect() + st.session.finish()
@@ -291,7 +304,7 @@ class Tenant:
     def _result(self, st: TenantStream, seg: bytes,
                 final: bool = False) -> api.FeedResult:
         if st.coalesced:
-            coal = self.coalescers[st.config]
+            coal = self.coalescers[(st.config, st.dtype)]
             try:
                 d = coal.stats(st.stream_id)
             except KeyError:  # already closed and retired

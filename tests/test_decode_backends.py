@@ -3,10 +3,10 @@
 The load-bearing acceptance ring: for every golden mode x D case, every
 backend in ``repro.core.decode.BACKENDS`` must reconstruct BYTE-identically
 to the host path -- full decodes, random single ranges, and batched
-multi-range plans.  The device backends' auto-fallback (exactness probe
-fails or the device path raises) must be logged and observable, never
-silent: a fallback that pretended to be a device result would make the
-parity sweep vacuous.
+multi-range plans.  A device backend the exactness probe finds inexact is
+routed to the host, logged and counted; a device backend that raises is a
+typed ``DeviceBackendError``, never a silent host result: a fallback that
+pretended to be a device result would make the parity sweep vacuous.
 """
 import logging
 import zlib
@@ -19,6 +19,7 @@ from repro.core import IdealemCodec
 from repro.core import decode as decode_mod
 from repro.core.decode import DecodePlan, PlanPart, pad_parts, reconstruct
 from repro.core.stream import decode_stream
+from repro.errors import DeviceBackendError, ReproError
 from repro.serve import DecompressionService, FlushPolicy
 from repro.store import Container, decode_range, decode_ranges, pack
 from test_golden_corpus import _golden_bytes
@@ -137,9 +138,10 @@ def test_fallback_is_logged_and_exact(monkeypatch, caplog):
     assert any("not byte-exact" in r.message for r in caplog.records)
 
 
-def test_crashing_device_backend_falls_back(monkeypatch, caplog):
+def test_crashing_device_backend_falls_back(monkeypatch):
+    """A device backend whose probe raises does NOT fall back: the call
+    raises the typed error and nothing is counted as served."""
     blob = _golden_bytes("std_D32")
-    want = decode_stream(blob)
 
     def crashing(plan, backend):
         raise RuntimeError("no f64 on this accelerator")
@@ -147,20 +149,19 @@ def test_crashing_device_backend_falls_back(monkeypatch, caplog):
     monkeypatch.setattr(decode_mod, "_run_device", crashing)
     monkeypatch.setattr(decode_mod, "_exact_cache", {})
     decode_mod.reset_decode_stats()
-    with caplog.at_level(logging.WARNING, logger="repro.core.decode"):
-        got = decode_stream(blob, backend="pallas")
-    assert got.tobytes() == want.tobytes()
-    assert decode_mod.decode_stats()["fallbacks"] == 1
-    assert any("falling back to host" in r.message for r in caplog.records)
+    with pytest.raises(DeviceBackendError, match="failed its probe"):
+        decode_stream(blob, backend="pallas")
+    stats = decode_mod.decode_stats()
+    assert stats["fallbacks"] == stats["host_calls"] == 0
+    assert stats["device_calls"] == 0
 
 
-def test_dispatch_failure_serves_from_host(monkeypatch, caplog):
+def test_dispatch_failure_serves_from_host(monkeypatch):
     """The probe can pass while the REAL (bigger) dispatch fails -- device
-    OOM, shape-specific compile error.  The call must then be served from
-    the host path instead of failing the request, and counted as a
-    fallback, not a device call."""
+    OOM, shape-specific compile error.  An explicit device backend then
+    raises the typed error (a ``ReproError`` with a wire code): the call
+    is neither served from the host nor counted as a device call."""
     blob = _golden_bytes("delta_D32")
-    want = decode_stream(blob)
     real_run = decode_mod._run_device
 
     def flaky(plan, backend):
@@ -171,17 +172,54 @@ def test_dispatch_failure_serves_from_host(monkeypatch, caplog):
     monkeypatch.setattr(decode_mod, "_run_device", flaky)
     monkeypatch.setattr(decode_mod, "_exact_cache", {})
     decode_mod.reset_decode_stats()
-    with caplog.at_level(logging.WARNING, logger="repro.core.decode"):
-        got = decode_stream(blob, backend="jax")
-    assert got.tobytes() == want.tobytes()
+    with pytest.raises(DeviceBackendError, match="failed at dispatch") as ei:
+        decode_stream(blob, backend="jax")
+    assert isinstance(ei.value, ReproError)
+    assert ei.value.code == "device_backend"
+    assert "RESOURCE_EXHAUSTED" in str(ei.value)
     stats = decode_mod.decode_stats()
-    assert stats["device_calls"] == 0 and stats["fallbacks"] == 1
-    assert any("failed at dispatch" in r.message for r in caplog.records)
+    assert stats["device_calls"] == 0
+    assert stats["fallbacks"] == stats["host_calls"] == 0
+
+
+def test_auto_routes_f64_to_host_counted(monkeypatch):
+    """On a device without f64 (the TPU), ``auto`` routes f64 plans to the
+    host by rule -- counted as ``host_routes``, never probed or caught --
+    while an explicit device backend refuses them with the typed error.
+    The device's lack of f64 is simulated here; f32 plans still run on
+    the device."""
+    monkeypatch.setattr(decode_mod, "_device_has_dtype",
+                        lambda dt: np.dtype(dt) != np.float64)
+    calls = []
+    real_run = decode_mod._run_device
+
+    def spy(plan, backend):
+        calls.append(np.dtype(plan.dtype))
+        return real_run(plan, backend)
+
+    monkeypatch.setattr(decode_mod, "_run_device", spy)
+    decode_mod.reset_decode_stats()
+    plan = decode_mod._probe_plan(decode_mod.MODE_DELTA, np.float64, None, 32)
+    want = decode_mod._reconstruct_numpy(plan)
+    for _ in range(2):
+        assert reconstruct(plan, backend="auto").tobytes() == want.tobytes()
+    stats = decode_mod.decode_stats()
+    assert stats["host_routes"] == 2 and stats["host_calls"] == 2
+    assert stats["autotune_probes"] == 0 and stats["fallbacks"] == 0
+    assert calls == []  # the device never saw the f64 plan
+    with pytest.raises(DeviceBackendError, match="no exact float64"):
+        reconstruct(plan, backend="pallas")
+    plan32 = decode_mod._probe_plan(decode_mod.MODE_DELTA, np.float32, None,
+                                    32)
+    got = reconstruct(plan32, backend="jax")
+    assert got.tobytes() == decode_mod._reconstruct_numpy(plan32).tobytes()
+    assert decode_mod.decode_stats()["device_calls"] == 1
 
 
 def test_fallback_probe_runs_once_per_combination(monkeypatch):
     """The probe is cached: a failing combination probes the device once,
-    then every later call routes straight to the host."""
+    then every later call raises the cached typed error without touching
+    the device again."""
     calls = []
 
     def crashing(plan, backend):
@@ -192,7 +230,8 @@ def test_fallback_probe_runs_once_per_combination(monkeypatch):
     monkeypatch.setattr(decode_mod, "_exact_cache", {})
     blob = _golden_bytes("std_D32")
     for _ in range(3):
-        decode_stream(blob, backend="jax")
+        with pytest.raises(DeviceBackendError, match="boom"):
+            decode_stream(blob, backend="jax")
     assert calls == ["jax"]
 
 
@@ -200,8 +239,8 @@ def test_fallback_probe_runs_once_per_combination(monkeypatch):
 def test_seq_cumsum_kernels_match_numpy_bitwise():
     """The delta-mode exactness story: XLA's associative cumsum is NOT
     byte-exact in f64, the sequential fori_loop and the pallas kernel are."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.seq_cumsum import seq_cumsum
 
@@ -210,7 +249,7 @@ def test_seq_cumsum_kernels_match_numpy_bitwise():
         x = rng.normal(0, 100, size=(rows, 31)).astype(dtype)
         x[0, 0] = -0.0  # a leading -0.0 must survive bit-for-bit
         want = np.cumsum(x, axis=1)
-        with enable_x64():
+        with jax.enable_x64(True):
             got = np.asarray(seq_cumsum(jnp.asarray(x)))
             assert got.tobytes() == want.tobytes(), dtype
             xla = np.asarray(jnp.cumsum(jnp.asarray(x), axis=1))

@@ -99,6 +99,31 @@ def test_minmax_gate_boundary():
     assert hits[1] and not hits[2]  # inclusive on, exclusive just-outside
 
 
+def test_minmax_gate_rounds_in_float32():
+    """Eq. 3's bound dmin + t rounds to float32 on every path, the numpy
+    oracle included: a candidate whose minimum sits on the rounded bound,
+    half an ulp above the exact one, hits everywhere.  (A float64 bound in
+    the oracle once turned this block into a miss -- micro-PMU voltages
+    near 7.2 kV, B=32 -- and the streams diverged from the device's.)"""
+    from repro.core.npref import encode_decisions_np
+
+    n = 32
+    lo, hi = np.float32(7204.3564), np.float32(7216.563)
+    t = (hi - lo) * np.float32(0.5)
+    bound = lo + t
+    assert float(bound) > float(lo) + float(t)  # rounded up in float32
+    entry = np.linspace(lo, hi, n, dtype=np.float32)
+    cand = np.linspace(bound, hi, n, dtype=np.float32)
+    assert entry[0] == lo and entry[-1] == hi and cand[0] == bound
+    blocks = np.stack([entry, cand])
+    kw = dict(num_dict=3, d_crit=2.0, rel_tol=0.5)  # KS always passes
+    want = encode_decisions_np(blocks, **kw)
+    assert want[0][1]
+    for matcher in ("reference", "ops", "fused"):
+        _assert_same_decisions(
+            want, encode_decisions(jnp.asarray(blocks), matcher=matcher, **kw))
+
+
 # ------------------------------------------------- streaming / masked cases
 def test_fused_resumable_state():
     blocks = jnp.asarray(_mixture_blocks(90, 24, seed=7))
