@@ -1,0 +1,12 @@
+"""Host time per feed request in stream assembly and framing: the sum of
+``repro_encode_phase_seconds{phase="commit"}`` over the feed requests,
+counted by the front end's parse phase of ``POST /v1/feed`` (one
+observation per request)."""
+PHASE = "commit"
+
+
+def read(ctx):
+    _s, requests = ctx.histogram("repro_frontend_phase_seconds",
+                                 {"route": "POST /v1/feed", "phase": "parse"})
+    s, n = ctx.histogram("repro_encode_phase_seconds", {"phase": PHASE})
+    return 1e3 * s / requests if requests and n else None

@@ -69,7 +69,16 @@ _M_DISPATCH = {
         "repro_encode_dispatches_total",
         "device encode-scan dispatches by path",
         labels={"path": path})
-    for path in ("adaptive_batched", "adaptive_loop")
+    for path in ("direct", "adaptive_batched", "adaptive_loop")
+}
+# Host phases of a session feed, each observed by the span of the same
+# section: prepare (cut and transform), dispatch (the direct scan's trace
+# and enqueue), sync (its device_get), commit (stream assembly and framing).
+_M_PHASE = {
+    phase: obs.registry().histogram(
+        "repro_encode_phase_seconds", "session feed host phases",
+        labels={"phase": phase})
+    for phase in ("prepare", "dispatch", "sync", "commit")
 }
 _M_COHORT = obs.registry().histogram(
     "repro_encode_adaptive_cohort",
@@ -386,10 +395,23 @@ class IdealemSession:
                 for ci in range(self._C)
             ]
         import jax
+        with obs.span("scan.dispatch", histogram=_M_PHASE["dispatch"]):
+            h, s, o = self._dispatch_scan(payload_cn, kw)
+        _M_DISPATCH["direct"].inc()
+        with obs.span("scan.sync", histogram=_M_PHASE["sync"]):
+            h, s, o = jax.device_get((h, s, o))
+        return [(h[ci], s[ci], o[ci]) for ci in range(self._C)]
+
+    def _dispatch_scan(self, payload_cn: np.ndarray, kw: dict):
+        """Enqueue the direct session's device scan; returns its
+        (unsynced) decision arrays and advances the carry."""
+        import jax
         import jax.numpy as jnp
         from .encoder import (encode_decisions_batched,
                               encode_decisions_dsharded,
                               encode_decisions_sharded, init_state)
+        cdc = self.codec
+        eb = kw.get("error_bound")
         matcher = getattr(cdc, "matcher", None)
         if cdc.backend == "pallas":
             # default to the fused single-dispatch kernel (bitwise-identical
@@ -400,7 +422,7 @@ class IdealemSession:
             kw["matcher"] = matcher
         if self.plan is not None:
             # scale-out path: channel axis sharded over the plan's mesh;
-            # pad rows are masked out of the scan and sliced off below.
+            # pad rows are masked out of the scan; _decide slices them off.
             plan = self.plan
             Cp = plan.padded_channels
             pad = Cp - self._C
@@ -433,8 +455,7 @@ class IdealemSession:
             # the carry is donated to the scan: the old state is consumed
             (h, s, o), self._dev_state = encode_decisions_batched(
                 pj, state=self._dev_state, **kw)
-        h, s, o = (np.asarray(v) for v in (h, s, o))
-        return [(h[ci], s[ci], o[ci]) for ci in range(self._C)]
+        return h, s, o
 
     # ------------------------------------------------- adaptive mode selection
     def _channel_kw(self, ci: int) -> dict:
@@ -629,6 +650,10 @@ class IdealemSession:
         full block completed.  ``feed`` is ``prepare`` + ``_decide`` +
         ``commit``; the serve-layer coalescer calls prepare/commit around
         one shared batched decide."""
+        with obs.span("session.prepare", histogram=_M_PHASE["prepare"]):
+            return self._prepare(chunk)
+
+    def _prepare(self, chunk) -> Optional[PreparedChunk]:
         if self._finished:
             raise RuntimeError("session already finished")
         arr = np.asarray(chunk)
@@ -668,6 +693,10 @@ class IdealemSession:
         """Apply per-channel decision triples for a prepared chunk: update
         stats and emit (or buffer) each channel's segment.  Always returns
         a per-channel list; decisions may cover only ``prep.nb`` blocks."""
+        with obs.span("session.commit", histogram=_M_PHASE["commit"]):
+            return self._commit(prep, decisions)
+
+    def _commit(self, prep: PreparedChunk, decisions) -> List[bytes]:
         outs = []
         total_hits = 0
         for ci in range(self._C):

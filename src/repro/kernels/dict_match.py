@@ -110,6 +110,7 @@ def dict_match_pallas(xs_sorted, dict_blocks, dmin, dmax, rel_tol,
     col = pl.BlockSpec((tile_d, 1), lambda i: (i, 0))
     ks, mm = pallas_call(
         _dict_match_kernel,
+        name="dict_match",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),        # rel_tol

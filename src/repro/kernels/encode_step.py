@@ -330,6 +330,7 @@ def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
         operands.insert(0, chan.reshape(1, -1))
     out = pallas_call(
         kernel,
+        name="encode_step",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -76,6 +76,7 @@ def flash_decode_pallas(q, k_cache, v_cache, valid):
     qg = q.reshape(B, Hkv, G, hd)
     out = pallas_call(
         _kernel,
+        name="flash_decode",
         grid=(B, C // chunk),
         in_specs=[
             pl.BlockSpec((1, Hkv, G, hd), lambda b, j: (b, 0, 0, 0)),

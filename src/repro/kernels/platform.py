@@ -19,14 +19,17 @@ from jax.experimental import pallas as pl
 __all__ = ["pallas_call"]
 
 
-def pallas_call(kernel, **kw):
-    """``pl.pallas_call(kernel, **kw)``, interpreted only when lowered for
-    the CPU backend and compiled for every other platform."""
+def pallas_call(kernel, *, name: str, **kw):
+    """``pl.pallas_call(kernel, name=name, **kw)``, interpreted only when
+    lowered for the CPU backend and compiled for every other platform.
+    ``name`` is the kernel's stable name: the compiled custom call is the
+    HLO instruction ``<name>.N``, which is what a profiler trace's op line
+    shows for it."""
     if "interpret" in kw:
         raise TypeError("interpret mode follows the lowering platform; "
                         "do not pass interpret=")
-    compiled = pl.pallas_call(kernel, **kw)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kw)
+    compiled = pl.pallas_call(kernel, name=name, **kw)
+    interpreted = pl.pallas_call(kernel, interpret=True, name=name, **kw)
 
     def call(*args):
         return jax.lax.platform_dependent(*args, cpu=interpreted,

@@ -54,6 +54,7 @@ def seq_cumsum_pallas(x):
                          f"use seq_cumsum, which pads")
     return pallas_call(
         _seq_cumsum_kernel,
+        name="seq_cumsum",
         grid=(R // TILE_R,),
         in_specs=[pl.BlockSpec((TILE_R, P), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((TILE_R, P), lambda i: (i, 0)),

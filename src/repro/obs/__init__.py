@@ -1,15 +1,17 @@
 """repro.obs -- unified telemetry: metrics registry + span tracer.
 
-Dependency-free (stdlib only) so every layer of the repo can import it
-without cycles: ``core``, ``store``, ``serve`` and the benchmarks all
-write into the process-default :func:`registry` and :func:`tracer`, and
-one snapshot sees the whole system (DESIGN.md Sec. 12).
+Dependency-free (stdlib only; a span also opens a profiler ``TraceMe``
+once JAX is imported, see ``obs/trace.py``) so every layer of the repo
+can import it without cycles: ``core``, ``store``, ``serve`` and the
+benchmarks all write into the process-default :func:`registry` and
+:func:`tracer`, and one snapshot sees the whole system (DESIGN.md
+Sec. 12).
 
     from repro import obs
 
     obs.registry().counter("repro_encode_flushes_total").inc()
-    with obs.span("encode.flush", attrs={"streams": 8}):
-        ...
+    with obs.span("encode.flush", attrs={"streams": 8}, histogram=h):
+        ...                             # synchronous code only
     text = obs.to_prometheus()          # Prometheus exposition
     doc = obs.to_json()                 # JSON snapshot (metrics + spans)
 

@@ -79,6 +79,9 @@ _M_INFLIGHT = obs.registry().gauge(
 _M_FLUSH_AGE = obs.registry().histogram(
     "repro_serve_flush_age_seconds",
     "age of the oldest pending request when its batch was cut")
+_M_QUEUE_WAIT = obs.registry().histogram(
+    "repro_serve_queue_wait_seconds",
+    "per request: submit to the cut of the batch that takes it")
 _M_ENC_FLUSHES = obs.registry().counter(
     "repro_encode_flushes_total", "coalescer device flush batches")
 _M_ENC_FLUSH_SECONDS = obs.registry().histogram(
@@ -95,30 +98,13 @@ _M_STREAMS_OPEN = {
 
 
 def _staged(stage: str, seq: int, **attrs):
-    """Span + stage-latency histogram around one pipeline stage body.
-    The injected ``trace(stage, seq)`` hook fires at stage *start* only,
-    so it cannot time; this wrapper is where durations come from."""
-    return _StagedTimer(stage, seq, attrs)
+    """Span + stage-latency histogram around one pipeline stage body, one
+    measurement (the histogram is observed on clean exit only).  The
+    injected ``trace(stage, seq)`` hook fires at stage *start* only, so it
+    cannot time; this is where durations come from."""
+    return obs.span(f"serve.{stage}", attrs={"seq": seq, **attrs},
+                    histogram=_M_STAGE_SECONDS[stage])
 
-
-class _StagedTimer:
-    __slots__ = ("stage", "seq", "attrs", "_span", "_t0")
-
-    def __init__(self, stage, seq, attrs):
-        self.stage, self.seq, self.attrs = stage, seq, attrs
-
-    def __enter__(self):
-        self._span = obs.span(f"serve.{self.stage}",
-                              attrs={"seq": self.seq, **self.attrs})
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self._t0
-        if exc_type is None:
-            _M_STAGE_SECONDS[self.stage].observe(dt)
-        return self._span.__exit__(exc_type, exc, tb)
 
 __all__ = ["CompressionService", "StreamCoalescer", "DecompressionService"]
 
@@ -813,6 +799,13 @@ class DecompressionService:
         that is this very batch; at depth > 1 it is whatever batch(es)
         just COMPLETED, so correlate by request id, not by call.  Returns
         ``None`` while the policy holds."""
+        with obs.span("serve.submit", attrs={"request_id": request_id}):
+            return self._submit(request_id, store_id, start_block,
+                                stop_block, channel)
+
+    def _submit(self, request_id: str, store_id: str, start_block: int,
+                stop_block: int, channel: int
+                ) -> Optional[Dict[str, np.ndarray]]:
         self._check_open()
         store = self._store(store_id)
         total = store.total_blocks(channel)
@@ -873,9 +866,11 @@ class DecompressionService:
         too); callers correlating answers by id should ``pop`` entries they
         have handled."""
         self._check_open()
-        age = self._age()
-        if age is not None:  # flush age at cut: how long the oldest waited
-            _M_FLUSH_AGE.observe(age)
+        now = self._clock()
+        if self._pending:  # flush age at cut: how long the oldest waited
+            _M_FLUSH_AGE.observe(now - self._pending[0][5])
+        for r in self._pending:  # each request's wait, submit to this cut
+            _M_QUEUE_WAIT.observe(now - r[5])
         pending, self._pending = self._pending, []
         self._pending_blocks = 0
         out: Dict[str, np.ndarray] = self._take_early()

@@ -94,6 +94,20 @@ def _m_latency(route: str):
     return m
 
 
+_M_PHASE = {}
+
+
+def _m_phase(route: str, phase: str):
+    m = _M_PHASE.get((route, phase))
+    if m is None:
+        m = _M_PHASE[(route, phase)] = obs.registry().histogram(
+            "repro_frontend_phase_seconds",
+            "front-end host phases by route: parse (body split, JSON, typed "
+            "request) and encode (typed answer, JSON, base64)",
+            labels={"route": route, "phase": phase})
+    return m
+
+
 _M_REJECT = {}
 
 
@@ -119,6 +133,9 @@ _M_BYTES = {
         f"repro_frontend_bytes_{d}_total", f"front-end HTTP body bytes {d}")
     for d in ("in", "out")
 }
+_M_LOOP_LAG = obs.registry().histogram(
+    "repro_frontend_loop_lag_seconds",
+    "how late each deadline tick woke past its interval (event-loop lag)")
 _M_FORCE_FLUSH = obs.registry().counter(
     "repro_frontend_backpressure_flushes_total",
     "cohort flushes forced by global backpressure before rejecting")
@@ -268,8 +285,12 @@ class ServeFrontend:
 
     # ------------------------------------------------------------------ tick
     async def _ticker(self) -> None:
+        loop = asyncio.get_running_loop()
         while True:
+            t = loop.time()
             await asyncio.sleep(self.tick_interval_s)
+            _M_LOOP_LAG.observe(
+                max(0.0, loop.time() - t - self.tick_interval_s))
             try:
                 self.tick()
             except Exception:  # pragma: no cover - keep the loop alive
@@ -280,6 +301,10 @@ class ServeFrontend:
         ``max_age_s`` trigger), deliver completed decode batches, and --
         on its slower cadence -- run the control loop and broadcast any
         policy change to every tenant."""
+        with obs.span("frontend.tick"):
+            self._tick()
+
+    def _tick(self) -> None:
         self.tenants.poll_flushes()
         for mux in self._muxes.values():
             mux.poll()
@@ -375,28 +400,27 @@ class ServeFrontend:
             tenant_id = headers.get("x-tenant")
             if not tenant_id:
                 raise ApiError("missing x-tenant header")
-            lines = [ln for ln in body.split(b"\n") if ln.strip()]
-            if not lines:
-                raise ApiError("empty request body")
-            if len(lines) > 1 and path != "/v1/feed":
-                raise ApiError("JSON-lines batching is /v1/feed only")
-            docs = []
-            for ln in lines:
-                try:
-                    docs.append(json.loads(ln))
-                except ValueError as exc:
-                    raise ApiError(f"bad JSON: {exc}") from None
+            route = f"POST {path}"
+            with obs.span("frontend.parse",
+                          histogram=_m_phase(route, "parse")):
+                reqs = self._parse(path, body)
             outs = []
             status = 200
-            for doc in docs:
+            for req in reqs:
                 try:
-                    outs.append(await self._apply(path, tenant_id, doc))
+                    if isinstance(req, Exception):
+                        raise req
+                    outs.append(await self._apply(path, tenant_id, req))
                 except Exception as exc:  # noqa: BLE001 - typed below
                     st, payload = self._error(exc)
-                    if len(docs) == 1:
+                    if len(reqs) == 1:
                         status = st
                     outs.append(payload)
-            payload = ("\n".join(json.dumps(o) for o in outs) + "\n").encode()
+            with obs.span("frontend.encode",
+                          histogram=_m_phase(route, "encode")):
+                payload = ("\n".join(
+                    json.dumps(o if isinstance(o, dict) else o.to_json())
+                    for o in outs) + "\n").encode()
             extra = []
             if status in (429, 503) and len(outs) == 1:
                 retry = outs[0].get("error", {}).get("retry_after_s")
@@ -410,6 +434,33 @@ class ServeFrontend:
         except Exception as exc:  # pragma: no cover - defensive
             return (500, "application/json",
                     (json.dumps(error_payload(exc)) + "\n").encode(), [])
+
+    @staticmethod
+    def _parse(path: str, body: bytes) -> list:
+        """A POST body as one typed request per line (the line's document
+        where the route has no request type).  A line whose document does
+        not make its request type is kept as the exception, so that only
+        that line fails; bad JSON fails the whole request."""
+        lines = [ln for ln in body.split(b"\n") if ln.strip()]
+        if not lines:
+            raise ApiError("empty request body")
+        if len(lines) > 1 and path != "/v1/feed":
+            raise ApiError("JSON-lines batching is /v1/feed only")
+        typed = _REQUEST_TYPES.get(path)
+        reqs = []
+        for ln in lines:
+            try:
+                doc = json.loads(ln)
+            except ValueError as exc:
+                raise ApiError(f"bad JSON: {exc}") from None
+            if typed is None:
+                reqs.append(doc)
+                continue
+            try:
+                reqs.append(typed.from_json(doc))
+            except Exception as exc:  # noqa: BLE001 - answered per line
+                reqs.append(exc)
+        return reqs
 
     def _dispatch_get(self, path: str) -> Tuple[int, str, bytes, list]:
         if path == "/healthz":
@@ -460,7 +511,9 @@ class ServeFrontend:
         return status, payload
 
     # ---------------------------------------------------------------- routes
-    async def _apply(self, path: str, tenant_id: str, doc: object) -> dict:
+    async def _apply(self, path: str, tenant_id: str, doc: object):
+        """One parsed line applied to the tenant: an answer document, or
+        an ``api`` result whose ``to_json`` the encode phase calls."""
         tenant = self.tenants.get(tenant_id)
         if path == "/v1/open":
             if not isinstance(doc, dict):
@@ -479,17 +532,15 @@ class ServeFrontend:
             return {"stream_id": sid, "coalesce": coalesce, "dtype": dtype,
                     "config": cfg.to_json()}
         if path == "/v1/feed":
-            req = api.CompressRequest.from_json(doc)
             self._admit_global(tenant)
-            return tenant.feed(req).to_json()
+            return tenant.feed(doc)
         if path == "/v1/close":
             sid = self._stream_id(doc, "close")
-            return tenant.close_stream(sid).to_json()
+            return tenant.close_stream(sid)
         if path == "/v1/collect":
             sid = self._stream_id(doc, "collect")
             st = tenant.stream(sid)
-            return api.FeedResult(stream_id=sid,
-                                  segment=st.collect()).to_json()
+            return api.FeedResult(stream_id=sid, segment=st.collect())
         if path == "/v1/attach":
             if not isinstance(doc, dict):
                 raise ApiError("attach: expected object")
@@ -509,18 +560,17 @@ class ServeFrontend:
             tenant.detach_store(store_id)
             return {"store_id": store_id, "detached": True}
         if path == "/v1/decode":
-            req = api.DecodeRangeRequest.from_json(doc)
             mux = self._mux(tenant)
-            fut = mux.submit(req)
+            fut = mux.submit(doc)
             try:
                 values = await asyncio.wait_for(fut, self.request_timeout_s)
             except asyncio.TimeoutError:
-                mux.futures.pop(req.request_id, None)
+                mux.futures.pop(doc.request_id, None)
                 raise OverloadedError(
                     "decode batch did not complete within "
                     f"{self.request_timeout_s}s") from None
-            return api.RangeResult(
-                request_id=req.request_id or "", values=values).to_json()
+            return api.RangeResult(request_id=doc.request_id or "",
+                                   values=values)
         raise NotFoundError(f"no route {path!r}")  # pragma: no cover
 
     @staticmethod
@@ -563,6 +613,9 @@ class ServeFrontend:
 _REASONS = {200: "OK", 400: "Bad Request", 404: "Not Found",
             429: "Too Many Requests", 500: "Internal Server Error",
             503: "Service Unavailable"}
+# routes whose lines parse into a typed request before they are applied
+_REQUEST_TYPES = {"/v1/feed": api.CompressRequest,
+                  "/v1/decode": api.DecodeRangeRequest}
 _POST_ROUTES = {"/v1/open", "/v1/feed", "/v1/close", "/v1/collect",
                 "/v1/attach", "/v1/detach", "/v1/decode"}
 

@@ -38,7 +38,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from repro import api
+from repro import api, obs
 from repro.errors import (ApiError, NotFoundError, QuotaExceededError,
                           RateLimitedError)
 
@@ -239,7 +239,14 @@ class Tenant:
 
     def feed(self, req: api.CompressRequest) -> api.FeedResult:
         """Apply one wire feed; typed admission first, then the stream's
-        service shape (direct dispatch vs coalesced staging)."""
+        service shape (direct dispatch vs coalesced staging).  Its span
+        carries the tenant and stream ids that the session's spans nest
+        under."""
+        with obs.span("tenant.feed",
+                      attrs={"tenant": self.id, "stream": req.stream_id}):
+            return self._feed(req)
+
+    def _feed(self, req: api.CompressRequest) -> api.FeedResult:
         st = self.stream(req.stream_id)
         arr = np.asarray(req.samples)
         self.admit_bytes(arr.nbytes)

@@ -4,8 +4,9 @@ Four property groups: (1) the instruments themselves -- counter/gauge/
 histogram semantics, Prometheus ``le`` bucket boundaries, label children,
 type-conflict rejection, and exact totals under concurrent writers; (2)
 the span tracer -- parent/child nesting per thread, error status, bounded
-ring eviction, exporter isolation; (3) the exporters -- a golden-format
-Prometheus text pin, the parse round trip, and the JSON snapshot shape;
+ring eviction (the profiler side is in ``test_spans.py``); (3) the
+exporters -- a golden-format Prometheus text pin, the parse round trip,
+and the JSON snapshot shape;
 (4) the wiring -- the acceptance shape of ISSUE 8: ONE pipelined
 ``DecompressionService`` flush with ``backend="auto"`` must land stage
 latency histograms for all four stages, autotune probe/hit counters,
@@ -201,26 +202,6 @@ def test_span_threads_nest_independently():
         (inner,) = trc.records(name=f"t{i}.inner")
         (outer,) = trc.records(name=f"t{i}.outer")
         assert inner.parent_id == outer.span_id
-
-
-def test_exporters_receive_records_and_bad_ones_are_dropped():
-    trc = SpanTracer()
-    seen = []
-    calls = []
-
-    def good(rec):
-        seen.append(rec.name)
-
-    def bad(rec):
-        calls.append(rec.name)
-        raise ValueError("poison")
-
-    trc.add_exporter(good)
-    trc.add_exporter(bad)
-    trc.event("a")
-    trc.event("b")
-    assert seen == ["a", "b"]
-    assert calls == ["a"]  # dropped after the first raise
 
 
 def test_disabled_tracer_records_nothing():

@@ -5,17 +5,18 @@ rank-1 blocks that are not 128-lane multiples, scalar stores to VMEM, a
 kernel that outgrows scoped VMEM.  These tests compile each kernel at the
 paper's Table I widths (D=255 padded to 256; n=32 for magnitude blocks,
 n=111 for B=112 delta payloads) for a chip that is described, not attached,
-and check that the compiled program holds the kernel (``tpu_custom_call``)
-and not the interpreter.  Each kernel also compiles vmapped over a channel
-axis, the shape batched sessions, the coalescer and the sharded scans
-dispatch.  Nothing runs, so nothing here says anything about results or
-times.
+and check that the compiled program holds the kernel (``tpu_custom_call``),
+under its stable name, and not the interpreter.  Each kernel also
+compiles vmapped over a channel axis, the shape batched sessions, the
+coalescer and the sharded scans dispatch.  Nothing runs, so nothing here
+says anything about results or times.
 
 The topology is described inside a fixture: only one process at a time
 may load the TPU compiler library, so it must never happen at import.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,16 +46,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *shapes, channels=0):
+def _named_kernel(text, kernel):
+    """The compiled program holds the Mosaic kernel as the custom call
+    named ``kernel`` (``%<kernel>.N``), the name a trace's op line shows."""
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*"
+                     rf'custom_call_target="tpu_custom_call"', text), kernel
+
+
+def _compile(fn, *shapes, kernel, channels=0):
     """Compile ``fn`` for the described chip, vmapped over a leading
     channel axis of every operand when ``channels``; the program must hold
-    a Mosaic kernel."""
+    the Mosaic kernel under its stable name."""
     if channels:
         fn = jax.vmap(fn)
         shapes = [jax.ShapeDtypeStruct((channels, *a.shape), a.dtype,
                                        sharding=a.sharding) for a in shapes]
     text = jax.jit(fn).lower(*shapes).compile().as_text()
-    assert "tpu_custom_call" in text
+    _named_kernel(text, kernel)
     return text
 
 
@@ -89,7 +97,7 @@ def test_encode_step_compiles(one_chip, variant, n, tile_d, channels):
             *carry, chan = a
             return encode_step_pallas(*carry, chan=chan, **kw)
         args += [s((8,), jnp.float32)]
-    _compile(fn, *args, channels=channels)
+    _compile(fn, *args, kernel="encode_step", channels=channels)
 
 
 @pytest.mark.parametrize("channels", [0, 4])
@@ -99,13 +107,14 @@ def test_dict_match_compiles(one_chip, n, channels):
     _compile(functools.partial(dict_match_pallas, rel_tol=0.5),
              s((n,), jnp.float32), s((DP, n), jnp.float32),
              s((DP,), jnp.float32), s((DP,), jnp.float32),
-             channels=channels)
+             kernel="dict_match", channels=channels)
 
 
 @pytest.mark.parametrize("P", [31, 111])
 def test_seq_cumsum_compiles(one_chip, P):
     _compile(seq_cumsum_pallas,
-             jax.ShapeDtypeStruct((64, P), jnp.float32, sharding=one_chip))
+             jax.ShapeDtypeStruct((64, P), jnp.float32, sharding=one_chip),
+             kernel="seq_cumsum")
 
 
 @pytest.mark.parametrize("mode,block_size,value_range", [
@@ -123,4 +132,4 @@ def test_device_decode_compiles(one_chip, mode, block_size, value_range):
         s((128, P), jnp.float32), s((64,), jnp.int32), last
     ).compile().as_text()
     if mode == decode_mod.MODE_DELTA:
-        assert "tpu_custom_call" in text  # the sequential-cumsum kernel
+        _named_kernel(text, "seq_cumsum")  # the sequential-cumsum kernel
