@@ -39,6 +39,7 @@ from typing import Callable, NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .ks import ks_statistic_many, ks_statistic_many_masked
 from .tuning import MeasuredTuner, best_of
 
@@ -329,10 +330,43 @@ def _step_fused(tile_d: int, params: EncoderParams, num_dict: int,
                        dec[DEC_OVER].astype(bool))
 
 
+def _direct_one(matcher, params: EncoderParams, num_dict: int):
+    """Per-channel scan body shared by the one-channel, the vmapped and
+    the channel-sharded direct scans (as ``_mixed_one`` is by the mixed
+    ones): the fused or reference ``lax.scan`` over pre-sorted rows, on
+    the logical-D carry."""
+
+    def one(s, b, xsb, v):
+        if _is_fused(matcher):
+            tile_d = matcher[1]
+            ps = _pad_state_d(s, (-num_dict) % tile_d)
+            step = functools.partial(_step_fused, tile_d, params, num_dict)
+            new_s, out = jax.lax.scan(step, ps, (b, xsb, v))
+            return out, _slice_state_d(new_s, num_dict)
+        step = functools.partial(_step, matcher, params)
+        new_s, out = jax.lax.scan(step, s, (b, xsb, v))
+        return out, new_s
+
+    return one
+
+
+# Traces of the batched direct scan: the jitted body increments it, and
+# the body runs only while JAX traces a new shape or static parameter, so
+# in steady state it stays flat while the session's
+# ``repro_encode_dispatches_total{path="direct"}`` grows.
+_M_SCAN_TRACES = obs.registry().counter(
+    "repro_encode_scan_traces_total",
+    "traces of the jitted encode scan (one per new shape or codec)",
+    labels={"path": "direct"})
+
+
 @functools.lru_cache(maxsize=None)
-def _encode_scan():
-    """Build the jitted scan lazily so importing this module never touches
-    the accelerator runtime (decode-only / numpy-backend processes).
+def _encode_scan(batched: bool):
+    """Build the jitted direct scan lazily so importing this module never
+    touches the accelerator runtime (decode-only / numpy-backend
+    processes).  ``batched`` vmaps the per-channel body over a leading
+    channel axis inside the jit, so a multi-channel call is one cached
+    executable per shape, enqueued without re-tracing.
 
     Buffer donation of the resumable carry is a device-memory optimization;
     the CPU backend does not implement it and warns, so gate on backend.
@@ -352,20 +386,14 @@ def _encode_scan():
             use_ks=use_ks, error_bound=error_bound,
             error_cumulative=error_cumulative,
         )
+        if valid is None:
+            valid = jnp.ones(blocks.shape[:-1], dtype=bool)
         xs_all = jnp.sort(blocks, axis=-1)  # hoisted out of the scan step
-        if _is_fused(matcher):
-            tile_d = matcher[1]
-            num_dict = state.sorted_blocks.shape[0]
-            pstate = _pad_state_d(state, (-num_dict) % tile_d)
-            step = functools.partial(_step_fused, tile_d, params, num_dict)
-            new_state, (is_hit, slot, overwrite) = jax.lax.scan(
-                step, pstate, (blocks, xs_all, valid))
-            new_state = _slice_state_d(new_state, num_dict)
-        else:
-            step = functools.partial(_step, matcher, params)
-            new_state, (is_hit, slot, overwrite) = jax.lax.scan(
-                step, state, (blocks, xs_all, valid))
-        return (is_hit, slot, overwrite), new_state
+        one = _direct_one(matcher, params, state.sorted_blocks.shape[-2])
+        if not batched:
+            return one(state, blocks, xs_all, valid)
+        _M_SCAN_TRACES.inc()
+        return jax.vmap(one)(state, blocks, xs_all, valid)
 
     return scan
 
@@ -534,18 +562,32 @@ def encode_decisions(
     name -- ``"reference"``/``"ops"``/``"fused"``/``"auto"`` -- resolved by
     :func:`resolve_matcher`.
     """
+    return _encode_direct(
+        blocks, None, num_dict=num_dict, d_crit=d_crit, rel_tol=rel_tol,
+        use_minmax=use_minmax, use_ks=use_ks, error_bound=error_bound,
+        error_cumulative=error_cumulative, matcher=matcher, state=state,
+        valid=valid)
+
+
+def _encode_direct(blocks, channels, *, num_dict, d_crit, rel_tol=0.1,
+                   use_minmax=True, use_ks=True, error_bound=None,
+                   error_cumulative=False, matcher=None, state=None,
+                   valid=None):
+    """One jitted call of the direct scan, one-channel (``channels=None``)
+    or batched over a leading axis of ``channels``.  The matcher is
+    resolved here, before any trace (a cold ``"auto"`` probe must run
+    eagerly), and a fresh carry is built for one-shot calls."""
     matcher = resolve_matcher(matcher, num_dict=num_dict,
                               n=blocks.shape[-1], dtype=blocks.dtype)
     return_state = state is not None
     if state is None:
         state = init_state(num_dict, blocks.shape[-1], dtype=blocks.dtype,
-                           raw=error_bound is not None)
+                           channels=channels, raw=error_bound is not None)
     if error_bound is not None and state.raw_blocks.shape[-2] == 0:
         raise ValueError("error_bound requires a state created with "
                          "init_state(..., raw=True)")
-    out, new_state = _encode_scan()(
-        state, blocks,
-        jnp.ones(blocks.shape[0], dtype=bool) if valid is None else valid,
+    out, new_state = _encode_scan(channels is not None)(
+        state, blocks, valid,
         d_crit=float(d_crit), rel_tol=float(rel_tol),
         use_minmax=use_minmax, use_ks=use_ks, matcher=matcher,
         error_bound=None if error_bound is None else float(error_bound),
@@ -564,33 +606,19 @@ def encode_decisions_batched(
 ):
     """Multi-channel encoder: blocks (C, nb, n) with per-channel DictState.
 
-    One vmapped scan encodes all channels in lockstep.  One-shot
-    (``state=None``) returns the (C, nb) decision triple; resumable
-    (``state=init_state(..., channels=C)`` or a previous return) returns
-    ``((is_hit, slot, overwrite), new_state)`` with the carry stacked on
-    the leading channel axis.  ``valid`` (C, nb) masks padded blocks of
-    ragged channels (coalesced serving batches).
+    One vmapped scan encodes all channels in lockstep, as one jitted call:
+    the executable is cached per shape and static codec parameters, so a
+    steady feed only enqueues it (no trace, no eager work around it).
+    One-shot (``state=None``) returns the (C, nb) decision triple;
+    resumable (``state=init_state(..., channels=C)`` or a previous return)
+    returns ``((is_hit, slot, overwrite), new_state)`` with the carry
+    stacked on the leading channel axis, donated on accelerators like
+    ``encode_decisions``.  ``valid`` (C, nb) masks padded blocks of ragged
+    channels (coalesced serving batches).  ``kw`` are the codec keywords
+    of :func:`encode_decisions`.
     """
-    # resolve names here, outside the vmap trace (a cold "auto" probe must
-    # run eagerly); the inner per-channel resolution is then a no-op
-    kw["matcher"] = resolve_matcher(
-        kw.get("matcher"), num_dict=num_dict, n=blocks_cn.shape[-1],
-        dtype=blocks_cn.dtype)
-    return_state = state is not None
-    if state is None:
-        state = init_state(
-            num_dict, blocks_cn.shape[-1], dtype=blocks_cn.dtype,
-            channels=blocks_cn.shape[0],
-            raw=kw.get("error_bound") is not None,
-        )
-    if valid is None:
-        valid = jnp.ones(blocks_cn.shape[:2], dtype=bool)
-
-    def one(s, b, v):
-        return encode_decisions(b, num_dict=num_dict, state=s, valid=v, **kw)
-
-    out, new_state = jax.vmap(one)(state, blocks_cn, valid)
-    return (out, new_state) if return_state else out
+    return _encode_direct(blocks_cn, blocks_cn.shape[0], num_dict=num_dict,
+                          state=state, valid=valid, **kw)
 
 
 # ------------------------------------------- masked mixed-mode (adaptive)
@@ -972,22 +1000,10 @@ def _sharded_scan(mesh, axis_name: str):
                                use_minmax=use_minmax, use_ks=use_ks,
                                error_bound=error_bound,
                                error_cumulative=error_cumulative)
-        num_dict = state.sorted_blocks.shape[-2]
-        if _is_fused(matcher):
-            tile_d = matcher[1]
-            step = functools.partial(_step_fused, tile_d, params, num_dict)
-        else:
-            step = functools.partial(_step, matcher, params)
+        one = _direct_one(matcher, params, state.sorted_blocks.shape[-2])
 
         def shard(s, b, v):
             x = jnp.sort(b, axis=-1)  # hoisted out of the scan step
-
-            def one(s1, b1, x1, v1):
-                if _is_fused(matcher):
-                    s1 = _pad_state_d(s1, (-num_dict) % matcher[1])
-                new_s, out = jax.lax.scan(step, s1, (b1, x1, v1))
-                return out, _slice_state_d(new_s, num_dict)
-
             return jax.vmap(one)(s, b, x, v)
 
         # check_vma=False: the pallas matcher has no replication rule; all

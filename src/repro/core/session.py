@@ -71,9 +71,13 @@ _M_DISPATCH = {
         labels={"path": path})
     for path in ("direct", "adaptive_batched", "adaptive_loop")
 }
+# The direct path's trace count, ``repro_encode_scan_traces_total``, is
+# registered beside the jitted scan it counts (``core.encoder``): it stays
+# flat while ``direct`` dispatches grow once every feed shape is compiled.
 # Host phases of a session feed, each observed by the span of the same
-# section: prepare (cut and transform), dispatch (the direct scan's trace
-# and enqueue), sync (its device_get), commit (stream assembly and framing).
+# section: prepare (cut and transform), dispatch (the enqueue of the direct
+# scan's cached executable), sync (its device_get), commit (stream assembly
+# and framing).
 _M_PHASE = {
     phase: obs.registry().histogram(
         "repro_encode_phase_seconds", "session feed host phases",

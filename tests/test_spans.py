@@ -143,6 +143,25 @@ def test_direct_session_feed_counts_one_scan_dispatch(backend, channels,
                     "dispatch": dispatches, "sync": dispatches, "commit": 1}
 
 
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_direct_scan_traces_once_per_feed_shape(backend):
+    """The direct scan is one cached executable per feed shape: three
+    same-shape feeds trace it once, a feed at a new block count once more,
+    while every feed counts its one dispatch."""
+    # an alpha no other test uses: its d_crit keys a fresh executable
+    sess = IdealemCodec(mode="std", block_size=32, num_dict=15, alpha=0.0173,
+                        backend=backend).session(channels=3)
+    x = np.sin(np.linspace(0, 20, 6 * 32))
+    traces = ("repro_encode_scan_traces_total", {"path": "direct"})
+    direct = ("repro_encode_dispatches_total", {"path": "direct"})
+    t0, d0 = _count(*traces), _count(*direct)
+    for lo in (0, 32, 64):
+        sess.feed(np.stack([x[lo:lo + 96]] * 3))
+    assert (_count(*traces) - t0, _count(*direct) - d0) == (1, 3)
+    sess.feed(np.stack([x[:160]] * 3))
+    assert (_count(*traces) - t0, _count(*direct) - d0) == (2, 4)
+
+
 def test_feed_and_decode_move_each_layer_count():
     feed, dec = "POST /v1/feed", "POST /v1/decode"
     keys = {

@@ -87,6 +87,54 @@ def test_batched_state_matches_per_channel():
         np.testing.assert_array_equal(np.asarray(o[ci]), np.asarray(oc))
 
 
+@pytest.mark.parametrize("matcher", ["reference", "fused"])
+@pytest.mark.parametrize("chunks", [[40], [13, 7, 20]])
+def test_batched_chunks_match_chained_channels(matcher, chunks):
+    """Chunked batched calls over C=3 channels with a ragged ``valid`` mask
+    give the decisions and carry of chaining each channel's own
+    ``encode_decisions`` calls over the same chunks and mask (the batched
+    scan is one jitted vmap of the same per-channel body)."""
+    import jax.numpy as jnp
+    from repro.core.encoder import (encode_decisions,
+                                    encode_decisions_batched, init_state)
+    C, nb, n = 3, sum(chunks), 16
+    blocks = jnp.asarray(np.stack([_mixed(nb * n, seed=ci).reshape(nb, n)
+                                   for ci in range(C)]), jnp.float32)
+    # ragged: channel ci's real blocks stop short of each chunk's end
+    valid = np.ones((C, nb), bool)
+    lo = 0
+    for size in chunks:
+        for ci in range(C):
+            valid[ci, lo + max(size - 2 * ci, 1):lo + size] = False
+        lo += size
+    valid = jnp.asarray(valid)
+    kw = dict(num_dict=5, d_crit=0.45, rel_tol=0.5, matcher=matcher)
+    state = init_state(5, n, channels=C)
+    per = [init_state(5, n) for _ in range(C)]
+    got, want = [], [[] for _ in range(C)]
+    lo = 0
+    for size in chunks:
+        sl = slice(lo, lo + size)
+        out, state = encode_decisions_batched(
+            blocks[:, sl], state=state, valid=valid[:, sl], **kw)
+        got.append(out)
+        for ci in range(C):
+            o, per[ci] = encode_decisions(blocks[ci, sl], state=per[ci],
+                                          valid=valid[ci, sl], **kw)
+            want[ci].append(o)
+        lo += size
+    for i in range(3):
+        g = np.concatenate([np.asarray(o[i]) for o in got], axis=1)
+        for ci in range(C):
+            np.testing.assert_array_equal(
+                g[ci], np.concatenate([np.asarray(o[i]) for o in want[ci]]))
+    for field in state._fields:
+        for ci in range(C):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(state, field))[ci],
+                np.asarray(getattr(per[ci], field)))
+
+
 # ----------------------------------------------- session chunked == one-shot
 @pytest.mark.parametrize("mode,num_dict", [
     ("std", 255), ("std", 3), ("std", 1),

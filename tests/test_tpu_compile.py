@@ -24,7 +24,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import decode as decode_mod
-from repro.core.encoder import _FUSED_TILE_SWEEP
+from repro.core.encoder import (_FUSED_TILE_SWEEP, _encode_scan, init_state,
+                                resolve_matcher)
 from repro.kernels.dict_match import dict_match_pallas
 from repro.kernels.encode_step import encode_step_pallas
 from repro.kernels.seq_cumsum import seq_cumsum_pallas
@@ -98,6 +99,27 @@ def test_encode_step_compiles(one_chip, variant, n, tile_d, channels):
             return encode_step_pallas(*carry, chan=chan, **kw)
         args += [s((8,), jnp.float32)]
     _compile(fn, *args, kernel="encode_step", channels=channels)
+
+
+@pytest.mark.parametrize("nb,n", [(225, 32), (64, 111), (65, 111)])
+def test_direct_batched_scan_compiles(one_chip, nb, n):
+    """The direct session's batched scan at a micro-PMU feed's shapes (one
+    channel; a minute of magnitudes, 225 blocks of 32; of angles, 64 or 65
+    payloads of 111) is one program, named ``jit_scan...`` after the
+    jitted ``scan`` (the prefix the benchmark's roofline reader counts),
+    that holds the fused kernel."""
+    carry = jax.eval_shape(
+        functools.partial(init_state, NUM_DICT, n, channels=1))
+    carry = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), carry)
+    blocks = jax.ShapeDtypeStruct((1, nb, n), jnp.float32, sharding=one_chip)
+    matcher = resolve_matcher("fused", num_dict=NUM_DICT, n=n,
+                              dtype=jnp.float32)
+    text = _encode_scan(True).lower(
+        carry, blocks, None, d_crit=0.3, rel_tol=0.5, use_minmax=True,
+        use_ks=True, matcher=matcher).compile().as_text()
+    assert re.match(r"HloModule jit_scan\b", text), text[:80]
+    _named_kernel(text, "encode_step")
 
 
 @pytest.mark.parametrize("channels", [0, 4])
