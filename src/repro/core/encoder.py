@@ -99,10 +99,12 @@ class EncoderParams(NamedTuple):
     use_ks: bool = True  # False = min/max check alone (ablation)
     # error-bounded mode (2404.02840 taxonomy): a would-be hit whose
     # pointwise reconstruction error exceeds the bound is demoted to a miss.
-    # None disables the check; error_cumulative bounds the running cumsum of
-    # the payload difference instead (delta mode, where decoded samples are
-    # base + cumsum of stored diffs).
-    error_bound: Optional[float] = None
+    # None disables the check; inside the jitted scans it is the traced
+    # scalar ``_bound_arg`` builds (one executable for every bound).
+    # error_cumulative bounds the running cumsum of the payload difference
+    # instead (delta mode, where decoded samples are base + cumsum of
+    # stored diffs).
+    error_bound: Optional[jax.Array] = None
     error_cumulative: bool = False
 
 
@@ -165,6 +167,18 @@ def repad_state_n(state: DictState, n_new: int) -> DictState:
                           raw_blocks=raw)
 
 
+def _bound_arg(error_bound, dtype):
+    """The bound as the scans' traced operand: ``None`` (the mode is off,
+    a different program) or a 0-d array rounded to the payload dtype --
+    the rounding every comparison of it has always made -- so that every
+    value of the bound shares one executable per shape."""
+    import numpy as np
+
+    if error_bound is None:
+        return None
+    return np.asarray(float(error_bound), dtype=np.dtype(dtype))
+
+
 def _error_gate(block, raw_blocks, params: EncoderParams):
     """Per-entry pointwise error check: ``max|err| <= bound`` where err is
     the payload difference (std/residual: decoded samples differ from the
@@ -209,6 +223,10 @@ def _step(matcher, params: EncoderParams, state: DictState, blk):
     all-zero -- so channels with fewer real blocks than the padded batch
     (coalesced serving batches, sharded channel padding) stay
     decision-identical to an unpadded scan.
+
+    With an error bound the decision gains a fourth field, ``demoted``:
+    some valid entry passed the min/max gate and the bound excluded every
+    such entry (the fused kernel's ``DEC_DEMOTED``).
     """
     block, xs, valid = blk
     num_dict = state.sorted_blocks.shape[0]
@@ -221,8 +239,12 @@ def _step(matcher, params: EncoderParams, state: DictState, blk):
     ks_ok = (ks <= params.d_crit) if params.use_ks else ones
 
     ok = state.valid & mm_ok & ks_ok
+    demoted = ()
     if params.error_bound is not None:
-        ok = ok & _error_gate(block, state.raw_blocks, params)
+        err_ok = _error_gate(block, state.raw_blocks, params)
+        pre = state.valid & mm_ok
+        demoted = (valid & jnp.any(pre) & ~jnp.any(pre & err_ok),)
+        ok = ok & err_ok
     is_hit = jnp.any(ok) & valid
     first_hit = jnp.argmax(ok)  # lowest passing slot == early-exit result
 
@@ -250,7 +272,7 @@ def _step(matcher, params: EncoderParams, state: DictState, blk):
         count=state.count + do_ins.astype(jnp.int32),
         raw_blocks=raw_blocks,
     )
-    return new_state, (is_hit, slot, overwrite)
+    return new_state, (is_hit, slot, overwrite) + demoted
 
 
 # ------------------------------------------------------- fused kernel step
@@ -303,43 +325,76 @@ def _step_fused(tile_d: int, params: EncoderParams, num_dict: int,
     + arg-min + FIFO overwrite and returns the updated (padded) carry.
     Decision-identical to ``_step`` with the ``ops`` matcher (bitwise: same
     kernel arithmetic) and to ``matcher_reference`` (same decisions).  Like
-    ``_step`` it consumes pre-sorted rows from the batched sort stage."""
+    ``_step`` it consumes pre-sorted rows from the batched sort stage.  The
+    error-bounded scan runs ``_scan_fused_bounded`` instead."""
     from repro.kernels.encode_step import (DEC_COUNT, DEC_HIT, DEC_OVER,
                                            DEC_SLOT, encode_step_pallas)
 
     block, xs, valid = blk
-    if params.error_bound is None:
-        new_sorted, ndmin, ndmax, nvalid, dec = encode_step_pallas(
-            xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
-            state.count, valid, d_crit=params.d_crit, rel_tol=params.rel_tol,
-            use_minmax=params.use_minmax, use_ks=params.use_ks,
-            num_dict=num_dict, tile_d=tile_d)
-        new_raw = state.raw_blocks
-    else:
-        new_sorted, ndmin, ndmax, nvalid, new_raw, dec = encode_step_pallas(
-            xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
-            state.count, valid, d_crit=params.d_crit, rel_tol=params.rel_tol,
-            use_minmax=params.use_minmax, use_ks=params.use_ks,
-            num_dict=num_dict, tile_d=tile_d,
-            raw=block, raw_blocks=state.raw_blocks,
-            error_bound=params.error_bound,
-            error_cumulative=params.error_cumulative)
+    new_sorted, ndmin, ndmax, nvalid, dec = encode_step_pallas(
+        xs, state.sorted_blocks, state.dmin, state.dmax, state.valid,
+        state.count, valid, d_crit=params.d_crit, rel_tol=params.rel_tol,
+        use_minmax=params.use_minmax, use_ks=params.use_ks,
+        num_dict=num_dict, tile_d=tile_d)
     new_state = DictState(new_sorted, ndmin, ndmax, nvalid, dec[DEC_COUNT],
-                          new_raw)
+                          state.raw_blocks)
     return new_state, (dec[DEC_HIT].astype(bool), dec[DEC_SLOT],
                        dec[DEC_OVER].astype(bool))
+
+
+def _scan_fused_bounded(tile_d: int, params: EncoderParams, num_dict: int,
+                        state: DictState, blocks, xs_all, valid):
+    """The error-bounded fused scan over the (padded) carry: the carry
+    travels in the kernel's own layout (``BoundedCarry``), updated in
+    place by the aliased kernel, and each step emits its whole ``dec`` row
+    -- so a step is the kernel and little else (the per-step reshapes,
+    casts, carry copies and scalar outputs of ``_step_fused`` cost more
+    device time, and trace events, than the kernel's own work at small
+    n).  Decisions, carry and the ``demoted`` flags are bitwise those of
+    the kernel step by step."""
+    from repro.kernels.encode_step import (DEC_DEMOTED, DEC_HIT, DEC_OVER,
+                                           DEC_SLOT, BoundedCarry,
+                                           encode_step_bounded)
+
+    num_dp = state.sorted_blocks.shape[0]
+    carry = BoundedCarry(state.sorted_blocks, state.raw_blocks,
+                         state.dmin.reshape(num_dp, 1),
+                         state.dmax.reshape(num_dp, 1),
+                         state.valid.astype(jnp.int32).reshape(num_dp, 1),
+                         state.count)
+    kw = dict(d_crit=params.d_crit, rel_tol=params.rel_tol,
+              use_minmax=params.use_minmax, use_ks=params.use_ks,
+              num_dict=num_dict, tile_d=tile_d,
+              error_cumulative=params.error_cumulative)
+
+    def step(c, blk):
+        block, xs, v = blk
+        return encode_step_bounded(c, xs, block, v, params.error_bound, **kw)
+
+    c, dec = jax.lax.scan(step, carry, (blocks, xs_all, valid))
+    new_state = DictState(c.sorted_blocks, c.dmin.reshape(num_dp),
+                          c.dmax.reshape(num_dp),
+                          c.valid.reshape(num_dp) != 0, c.count,
+                          c.raw_blocks)
+    return new_state, (dec[:, DEC_HIT] != 0, dec[:, DEC_SLOT],
+                       dec[:, DEC_OVER] != 0, dec[:, DEC_DEMOTED] != 0)
 
 
 def _direct_one(matcher, params: EncoderParams, num_dict: int):
     """Per-channel scan body shared by the one-channel, the vmapped and
     the channel-sharded direct scans (as ``_mixed_one`` is by the mixed
     ones): the fused or reference ``lax.scan`` over pre-sorted rows, on
-    the logical-D carry."""
+    the logical-D carry.  With an error bound the per-block output holds
+    the ``demoted`` flags as a fourth field."""
 
     def one(s, b, xsb, v):
         if _is_fused(matcher):
             tile_d = matcher[1]
             ps = _pad_state_d(s, (-num_dict) % tile_d)
+            if params.error_bound is not None:
+                new_s, out = _scan_fused_bounded(tile_d, params, num_dict,
+                                                 ps, b, xsb, v)
+                return out, _slice_state_d(new_s, num_dict)
             step = functools.partial(_step_fused, tile_d, params, num_dict)
             new_s, out = jax.lax.scan(step, ps, (b, xsb, v))
             return out, _slice_state_d(new_s, num_dict)
@@ -370,13 +425,18 @@ def _encode_scan(batched: bool):
 
     Buffer donation of the resumable carry is a device-memory optimization;
     the CPU backend does not implement it and warns, so gate on backend.
+
+    ``error_bound`` is traced (``None`` or a 0-d array, ``_bound_arg``):
+    whether it is set picks the program, its value does not.  With a bound
+    the scan returns ``(decisions, carry, demotions)``, the last the count
+    of bound-demoted blocks per lane, fetched with the decisions.
     """
     donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
 
     @functools.partial(
         jax.jit,
         static_argnames=("d_crit", "rel_tol", "use_minmax", "use_ks",
-                         "matcher", "error_bound", "error_cumulative"),
+                         "matcher", "error_cumulative"),
         donate_argnums=donate,
     )
     def scan(state: DictState, blocks, valid, *, d_crit, rel_tol, use_minmax,
@@ -390,10 +450,14 @@ def _encode_scan(batched: bool):
             valid = jnp.ones(blocks.shape[:-1], dtype=bool)
         xs_all = jnp.sort(blocks, axis=-1)  # hoisted out of the scan step
         one = _direct_one(matcher, params, state.sorted_blocks.shape[-2])
-        if not batched:
-            return one(state, blocks, xs_all, valid)
-        _M_SCAN_TRACES.inc()
-        return jax.vmap(one)(state, blocks, xs_all, valid)
+        if batched:
+            _M_SCAN_TRACES.inc()
+            one = jax.vmap(one)
+        out, new_state = one(state, blocks, xs_all, valid)
+        if error_bound is None:
+            return out, new_state
+        return out[:3], new_state, jnp.sum(out[3], axis=-1,
+                                           dtype=jnp.int32)
 
     return scan
 
@@ -572,11 +636,13 @@ def encode_decisions(
 def _encode_direct(blocks, channels, *, num_dict, d_crit, rel_tol=0.1,
                    use_minmax=True, use_ks=True, error_bound=None,
                    error_cumulative=False, matcher=None, state=None,
-                   valid=None):
+                   valid=None, demotions=False):
     """One jitted call of the direct scan, one-channel (``channels=None``)
     or batched over a leading axis of ``channels``.  The matcher is
     resolved here, before any trace (a cold ``"auto"`` probe must run
-    eagerly), and a fresh carry is built for one-shot calls."""
+    eagerly), and a fresh carry is built for one-shot calls.  A resumable
+    call with a bound and ``demotions=True`` returns the scan's per-lane
+    demotion count as a third value."""
     matcher = resolve_matcher(matcher, num_dict=num_dict,
                               n=blocks.shape[-1], dtype=blocks.dtype)
     return_state = state is not None
@@ -586,14 +652,16 @@ def _encode_direct(blocks, channels, *, num_dict, d_crit, rel_tol=0.1,
     if error_bound is not None and state.raw_blocks.shape[-2] == 0:
         raise ValueError("error_bound requires a state created with "
                          "init_state(..., raw=True)")
-    out, new_state = _encode_scan(channels is not None)(
+    out, new_state, *dem = _encode_scan(channels is not None)(
         state, blocks, valid,
         d_crit=float(d_crit), rel_tol=float(rel_tol),
         use_minmax=use_minmax, use_ks=use_ks, matcher=matcher,
-        error_bound=None if error_bound is None else float(error_bound),
+        error_bound=_bound_arg(error_bound, blocks.dtype),
         error_cumulative=bool(error_cumulative),
     )
-    return (out, new_state) if return_state else out
+    if not return_state:
+        return out
+    return (out, new_state) + (tuple(dem) if demotions else ())
 
 
 def encode_decisions_batched(
@@ -615,7 +683,9 @@ def encode_decisions_batched(
     stacked on the leading channel axis, donated on accelerators like
     ``encode_decisions``.  ``valid`` (C, nb) masks padded blocks of ragged
     channels (coalesced serving batches).  ``kw`` are the codec keywords
-    of :func:`encode_decisions`.
+    of :func:`encode_decisions`; with an ``error_bound``, a resumable call
+    may pass ``demotions=True`` to get the (C,) count of blocks the bound
+    demoted as a third return, from the same scan.
     """
     return _encode_direct(blocks_cn, blocks_cn.shape[0], num_dict=num_dict,
                           state=state, valid=valid, **kw)
@@ -753,8 +823,7 @@ def _mixed_scan():
 
     @functools.partial(
         jax.jit,
-        static_argnames=("rel_tol", "use_minmax", "use_ks", "matcher",
-                         "error_bound"),
+        static_argnames=("rel_tol", "use_minmax", "use_ks", "matcher"),
         donate_argnums=donate,
     )
     def scan(state, blocks, valid, chan, *, rel_tol, use_minmax, use_ks,
@@ -827,7 +896,7 @@ def encode_decisions_mixed(
     axis via ``valid`` (C, nb).  ``n_valid`` (C,) gives each channel's
     logical payload width, ``d_crit`` (C,) its (selector-scaled) KS
     threshold, ``error_cumulative`` (C,) bools pick the cumsum error
-    metric per channel (delta mode) under the shared static
+    metric per channel (delta mode) under the shared (traced)
     ``error_bound``, and ``eb_on`` (C,) disarms the bound per channel.
 
     Decisions and the per-lane carry are bitwise identical to C separate
@@ -857,7 +926,7 @@ def encode_decisions_mixed(
     out, new_state = _mixed_scan()(
         state, blocks_cn, valid, chan, rel_tol=float(rel_tol),
         use_minmax=use_minmax, use_ks=use_ks, matcher=matcher,
-        error_bound=None if error_bound is None else float(error_bound),
+        error_bound=_bound_arg(error_bound, blocks_cn.dtype),
     )
     return (out, new_state) if return_state else out
 
@@ -877,28 +946,30 @@ def _mixed_sharded_scan(mesh, axis_name: str):
 
     @functools.partial(
         jax.jit,
-        static_argnames=("rel_tol", "use_minmax", "use_ks", "matcher",
-                         "error_bound"),
+        static_argnames=("rel_tol", "use_minmax", "use_ks", "matcher"),
         donate_argnums=donate,
     )
     def scan(state, blocks, valid, chan, *, rel_tol, use_minmax, use_ks,
              matcher, error_bound=None):
-        params = EncoderParams(d_crit=0.0, rel_tol=rel_tol,
-                               use_minmax=use_minmax, use_ks=use_ks,
-                               error_bound=error_bound)
         num_dict = state.sorted_blocks.shape[-2]
-        one = _mixed_one(matcher, params, num_dict)
 
-        def shard(s, b, v, cp):
+        def shard(s, b, v, cp, *bound):
+            # the bound enters replicated, as an operand of its own
+            params = EncoderParams(d_crit=0.0, rel_tol=rel_tol,
+                                   use_minmax=use_minmax, use_ks=use_ks,
+                                   error_bound=bound[0] if bound else None)
+            one = _mixed_one(matcher, params, num_dict)
             x = jnp.sort(b, axis=-1)
             return jax.vmap(one)(s, b, x, v, cp)
 
+        bound = () if error_bound is None else (error_bound,)
         return jax.shard_map(
             shard, mesh=mesh,
-            in_specs=(st_spec, blk_spec, msk_spec, chan_spec),
+            in_specs=(st_spec, blk_spec, msk_spec, chan_spec)
+            + (P(),) * len(bound),
             out_specs=(out_spec, st_spec),
             check_vma=False,
-        )(state, blocks, valid, chan)
+        )(state, blocks, valid, chan, *bound)
 
     return scan
 
@@ -948,7 +1019,7 @@ def encode_decisions_mixed_sharded(
     out, new_state = _mixed_sharded_scan(mesh, axis_name)(
         state, blocks_cn, valid, chan, rel_tol=float(rel_tol),
         use_minmax=use_minmax, use_ks=use_ks, matcher=matcher,
-        error_bound=None if error_bound is None else float(error_bound),
+        error_bound=_bound_arg(error_bound, blocks_cn.dtype),
     )
     return (out, new_state) if return_state else out
 
@@ -991,29 +1062,33 @@ def _sharded_scan(mesh, axis_name: str):
     @functools.partial(
         jax.jit,
         static_argnames=("d_crit", "rel_tol", "use_minmax", "use_ks",
-                         "matcher", "error_bound", "error_cumulative"),
+                         "matcher", "error_cumulative"),
         donate_argnums=donate,
     )
     def scan(state, blocks, valid, *, d_crit, rel_tol, use_minmax, use_ks,
              matcher, error_bound=None, error_cumulative=False):
-        params = EncoderParams(d_crit=d_crit, rel_tol=rel_tol,
-                               use_minmax=use_minmax, use_ks=use_ks,
-                               error_bound=error_bound,
-                               error_cumulative=error_cumulative)
-        one = _direct_one(matcher, params, state.sorted_blocks.shape[-2])
+        num_dict = state.sorted_blocks.shape[-2]
 
-        def shard(s, b, v):
+        def shard(s, b, v, *bound):
+            # the bound enters replicated, as an operand of its own
+            params = EncoderParams(d_crit=d_crit, rel_tol=rel_tol,
+                                   use_minmax=use_minmax, use_ks=use_ks,
+                                   error_bound=bound[0] if bound else None,
+                                   error_cumulative=error_cumulative)
+            one = _direct_one(matcher, params, num_dict)
             x = jnp.sort(b, axis=-1)  # hoisted out of the scan step
-            return jax.vmap(one)(s, b, x, v)
+            out, st = jax.vmap(one)(s, b, x, v)
+            return out[:3], st
 
         # check_vma=False: the pallas matcher has no replication rule; all
         # operands map over the channel axis anyway (no replicated outputs).
+        bound = () if error_bound is None else (error_bound,)
         return jax.shard_map(
             shard, mesh=mesh,
-            in_specs=(st_spec, blk_spec, msk_spec),
+            in_specs=(st_spec, blk_spec, msk_spec) + (P(),) * len(bound),
             out_specs=(out_spec, st_spec),
             check_vma=False,
-        )(state, blocks, valid)
+        )(state, blocks, valid, *bound)
 
     return scan
 
@@ -1062,7 +1137,7 @@ def encode_decisions_sharded(
         state, blocks_cn, valid, d_crit=float(d_crit),
         rel_tol=float(rel_tol), use_minmax=use_minmax, use_ks=use_ks,
         matcher=matcher,
-        error_bound=None if error_bound is None else float(error_bound),
+        error_bound=_bound_arg(error_bound, blocks_cn.dtype),
         error_cumulative=bool(error_cumulative),
     )
     return (out, new_state) if return_state else out
@@ -1166,15 +1241,11 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
     @functools.partial(
         jax.jit,
         static_argnames=("d_crit", "rel_tol", "use_minmax", "use_ks",
-                         "matcher", "error_bound", "error_cumulative"),
+                         "matcher", "error_cumulative"),
         donate_argnums=donate,
     )
     def scan(state, blocks, valid, *, d_crit, rel_tol, use_minmax, use_ks,
              matcher, error_bound=None, error_cumulative=False):
-        params = EncoderParams(d_crit=d_crit, rel_tol=rel_tol,
-                               use_minmax=use_minmax, use_ks=use_ks,
-                               error_bound=error_bound,
-                               error_cumulative=error_cumulative)
         num_dict = state.sorted_blocks.shape[1]
         shards = mesh.shape[dict_axis]
         pad = (-num_dict) % shards
@@ -1190,10 +1261,15 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
             count=state.count,
             raw_blocks=raw,
         )
-        step = functools.partial(_step_dshard, matcher, params, num_dict,
-                                 dict_axis)
 
-        def shard(s, b, v):
+        def shard(s, b, v, *bound):
+            # the bound enters replicated, as an operand of its own
+            params = EncoderParams(d_crit=d_crit, rel_tol=rel_tol,
+                                   use_minmax=use_minmax, use_ks=use_ks,
+                                   error_bound=bound[0] if bound else None,
+                                   error_cumulative=error_cumulative)
+            step = functools.partial(_step_dshard, matcher, params, num_dict,
+                                     dict_axis)
             x = jnp.sort(b, axis=-1)  # hoisted out of the scan step
 
             def one(s1, b1, x1, v1):
@@ -1202,12 +1278,13 @@ def _dsharded_scan(mesh, ch_axis: str, dict_axis: str):
 
             return jax.vmap(one)(s, b, x, v)
 
+        bound = () if error_bound is None else (error_bound,)
         out, new_p = jax.shard_map(
             shard, mesh=mesh,
-            in_specs=(st_spec, blk_spec, msk_spec),
+            in_specs=(st_spec, blk_spec, msk_spec) + (P(),) * len(bound),
             out_specs=(out_spec, st_spec),
             check_vma=False,
-        )(pstate, blocks, valid)
+        )(pstate, blocks, valid, *bound)
         new_state = DictState(
             sorted_blocks=new_p.sorted_blocks[:, :num_dict],
             dmin=new_p.dmin[:, :num_dict],
@@ -1273,7 +1350,7 @@ def encode_decisions_dsharded(
         state, blocks_cn, valid, d_crit=float(d_crit),
         rel_tol=float(rel_tol), use_minmax=use_minmax, use_ks=use_ks,
         matcher=matcher,
-        error_bound=None if error_bound is None else float(error_bound),
+        error_bound=_bound_arg(error_bound, blocks_cn.dtype),
         error_cumulative=bool(error_cumulative),
     )
     return (out, new_state) if return_state else out

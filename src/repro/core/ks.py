@@ -73,16 +73,19 @@ def _ecdf_distance_sorted(xs, ys):
     """sup_x |F_x - F_y| for sorted 1-D samples xs (n1,), ys (n2,).
 
     Evaluated at every sample point of both samples (ECDFs are right-
-    continuous step functions, the sup is attained at a jump point).
+    continuous step functions, the sup is attained at a jump point).  A
+    sample's own ECDF at its points is a ``side="right"`` count too, so
+    tied values (a constant block) take the count at the end of their run;
+    without ties it is ``1..n``.
     """
     n1 = xs.shape[0]
     n2 = ys.shape[0]
     # F at candidate points
-    fx_at_x = (jnp.arange(1, n1 + 1, dtype=jnp.float32)) / n1
+    fx_at_x = jnp.searchsorted(xs, xs, side="right").astype(jnp.float32) / n1
     fy_at_x = jnp.searchsorted(ys, xs, side="right").astype(jnp.float32) / n2
     d1 = jnp.max(jnp.abs(fx_at_x - fy_at_x))
     # F at dictionary points
-    fy_at_y = (jnp.arange(1, n2 + 1, dtype=jnp.float32)) / n2
+    fy_at_y = jnp.searchsorted(ys, ys, side="right").astype(jnp.float32) / n2
     fx_at_y = jnp.searchsorted(xs, ys, side="right").astype(jnp.float32) / n1
     d2 = jnp.max(jnp.abs(fx_at_y - fy_at_y))
     return jnp.maximum(d1, d2)
@@ -118,11 +121,10 @@ def _ecdf_distance_sorted_masked(xs, ys, nf, col_ok):
     zero-filled before the max (KS >= 0), so the result is bitwise
     identical to ``_ecdf_distance_sorted`` on the unpadded samples.
     """
-    m = xs.shape[0]
-    fx_at_x = (jnp.arange(1, m + 1, dtype=jnp.float32)) / nf
+    fx_at_x = jnp.searchsorted(xs, xs, side="right").astype(jnp.float32) / nf
     fy_at_x = jnp.searchsorted(ys, xs, side="right").astype(jnp.float32) / nf
     d1 = jnp.max(jnp.where(col_ok, jnp.abs(fx_at_x - fy_at_x), 0.0))
-    fy_at_y = (jnp.arange(1, m + 1, dtype=jnp.float32)) / nf
+    fy_at_y = jnp.searchsorted(ys, ys, side="right").astype(jnp.float32) / nf
     fx_at_y = jnp.searchsorted(xs, ys, side="right").astype(jnp.float32) / nf
     d2 = jnp.max(jnp.where(col_ok, jnp.abs(fx_at_y - fy_at_y), 0.0))
     return jnp.maximum(d1, d2)

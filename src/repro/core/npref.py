@@ -33,7 +33,9 @@ __all__ = [
 # Only this host reference walk can attribute reasons: the device scans
 # return hit/slot/overwrite without per-gate outcomes (DESIGN.md
 # Sec. 12), so these counters populate on numpy-matched sessions and the
-# differential oracle, not on fused-kernel encodes.
+# differential oracle, not on fused-kernel encodes.  Device encodes count
+# what the bound decides on their own: a direct scan returns the blocks it
+# demoted, ``repro_encode_bound_demotions_total`` (``core.session``).
 _MISS_COUNTERS = {
     reason: obs.registry().counter(
         "repro_encode_miss_total",

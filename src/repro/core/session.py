@@ -84,6 +84,13 @@ _M_PHASE = {
         labels={"phase": phase})
     for phase in ("prepare", "dispatch", "sync", "commit")
 }
+# Error-bounded direct scans: blocks the bound demoted -- some valid entry
+# passed the min/max gate and the bound excluded every such entry -- counted
+# on the device by the scan and fetched with its decisions.
+_M_DEMOTIONS = obs.registry().counter(
+    "repro_encode_bound_demotions_total",
+    "blocks the error bound demoted (an entry passed min/max, none the bound)",
+    labels={"path": "direct"})
 _M_COHORT = obs.registry().histogram(
     "repro_encode_adaptive_cohort",
     "channels covered per adaptive encode dispatch (cohort size)",
@@ -400,15 +407,19 @@ class IdealemSession:
             ]
         import jax
         with obs.span("scan.dispatch", histogram=_M_PHASE["dispatch"]):
-            h, s, o = self._dispatch_scan(payload_cn, kw)
+            out = self._dispatch_scan(payload_cn, kw)
         _M_DISPATCH["direct"].inc()
         with obs.span("scan.sync", histogram=_M_PHASE["sync"]):
-            h, s, o = jax.device_get((h, s, o))
+            h, s, o, *dem = jax.device_get(out)
+        if dem:
+            _M_DEMOTIONS.inc(int(dem[0][:self._C].sum()))
         return [(h[ci], s[ci], o[ci]) for ci in range(self._C)]
 
     def _dispatch_scan(self, payload_cn: np.ndarray, kw: dict):
         """Enqueue the direct session's device scan; returns its
-        (unsynced) decision arrays and advances the carry."""
+        (unsynced) decision arrays, and with an error bound on the
+        unplanned path the per-lane demotion count after them, and
+        advances the carry."""
         import jax
         import jax.numpy as jnp
         from .encoder import (encode_decisions_batched,
@@ -457,8 +468,9 @@ class IdealemSession:
                     cdc.num_dict, pj.shape[-1], dtype=jnp.float32,
                     channels=self._C, raw=eb is not None)
             # the carry is donated to the scan: the old state is consumed
-            (h, s, o), self._dev_state = encode_decisions_batched(
-                pj, state=self._dev_state, **kw)
+            (h, s, o), self._dev_state, *dem = encode_decisions_batched(
+                pj, state=self._dev_state, demotions=eb is not None, **kw)
+            return (h, s, o, *dem)
         return h, s, o
 
     # ------------------------------------------------- adaptive mode selection

@@ -65,9 +65,12 @@ def _dict_match_kernel(rtol_ref, xs_ref, dict_ref, dmin_ref, dmax_ref,
     # counts of dict values <= each candidate point: (TILE_D, n_x)
     cmp_d_le_x = (ds[:, :, None] <= xs[None, :, :]).astype(jnp.float32)
     cnt_d = jnp.sum(cmp_d_le_x, axis=1)                        # (TILE_D, n)
-    f_x_at_x = (jax.lax.broadcasted_iota(jnp.int32, (1, n), 1
-                                         ).astype(jnp.float32)
-                + 1.0) * inv_n                                 # (1, n)
+    # F_x at its own points: the count of candidate values <= each one
+    # (j + 1 at position j unless values tie: a tied run takes the count
+    # at its end, as the ECDF does)
+    cnt_xx = jnp.sum((xs[:, None, :] <= xs[:, :, None]).astype(jnp.float32),
+                     axis=2)                                   # (1, n)
+    f_x_at_x = cnt_xx * inv_n                                  # (1, n)
     d1 = jnp.max(jnp.abs(f_x_at_x - cnt_d * inv_n), axis=1, keepdims=True)
 
     # counts of candidate values <= each dict point: (TILE_D, n_d)

@@ -32,10 +32,11 @@ Layout (what Mosaic accepts on the TPU): the per-entry vectors dmin, dmax
 and valid travel as (Dp, 1) columns tiled (tile_d, 1), so they broadcast
 against a (tile_d, n) dictionary tile and tile_d only has to be a multiple
 of 8 sublanes; ``valid`` is int32 inside the kernel.  The scalars -- the
-``[count, block_valid]`` meta operand, the per-channel parameters and the
-decision block -- live in SMEM as (1, k) rows: a vmapped channel axis then
-adds a leading block dimension Mosaic accepts (a 1-D SMEM operand batched
-to (C, k) is refused).  The candidate is a (1, n) row.
+``[count, block_valid]`` meta operand, the per-channel parameters, the
+error bound and the decision block -- live in SMEM as (1, k) rows: a
+vmapped channel axis then adds a leading block dimension Mosaic accepts
+(a 1-D SMEM operand batched to (C, k) is refused).  The candidate is a
+(1, n) row.
 
 D must be padded to a ``tile_d`` multiple with ``valid=False`` rows (the
 encoder pads once at scan entry); padded rows never pass the gate and are
@@ -45,6 +46,7 @@ never inserted because the FIFO slot is ``count % num_dict`` with the
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -54,17 +56,22 @@ from jax.experimental.pallas import tpu as pltpu
 from .dict_match import TILE_D, check_tile_divisible
 from .platform import pallas_call
 
-__all__ = ["encode_step_pallas", "SENTINEL",
-           "DEC_BEST", "DEC_HIT", "DEC_SLOT", "DEC_OVER", "DEC_COUNT",
-           "CHAN_NF", "CHAN_INV_N", "CHAN_DCRIT", "CHAN_ERRCUM",
-           "CHAN_EBON"]
+__all__ = ["encode_step_pallas", "encode_step_bounded", "BoundedCarry",
+           "SENTINEL", "DEC_BEST", "DEC_HIT", "DEC_SLOT", "DEC_OVER",
+           "DEC_COUNT", "DEC_DEMOTED", "CHAN_NF", "CHAN_INV_N", "CHAN_DCRIT",
+           "CHAN_ERRCUM", "CHAN_EBON"]
 
 # "no entry passed" marker for the running arg-min; any real global index
 # (< 2^8 dictionary rows) is far below it.
 SENTINEL = 2 ** 30
 
-# layout of the (8,) int32 decision block (rows 5..7 are padding)
-DEC_BEST, DEC_HIT, DEC_SLOT, DEC_OVER, DEC_COUNT = range(5)
+# layout of the (8,) int32 decision block.  With an error bound armed,
+# DEC_DEMOTED flags a block the bound demoted (some valid entry passed the
+# min/max gate and the bound excluded every such entry) and DEC_EB_PASS is
+# the running "some entry passed both" accumulator it is finalized from;
+# row 7 is padding.
+DEC_BEST, DEC_HIT, DEC_SLOT, DEC_OVER, DEC_COUNT, DEC_DEMOTED, DEC_EB_PASS = \
+    range(7)
 
 # layout of the optional (8,) f32 per-channel parameter operand (mixed-mode
 # adaptive cohorts, DESIGN.md Sec. 13; rows 5..7 are padding)
@@ -90,20 +97,20 @@ def _max_abs_cumsum(diff, col_ok=None):
     return m
 
 
-def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
+def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, eb,
                         error_cumulative, num_dict, tile_d, chan, *refs):
-    chan_ref = None
+    chan_ref = bound_ref = None
     if chan:
         chan_ref, *refs = refs
-    if error_bound is None:
+    if not eb:
         (meta_ref, xs_ref, dict_ref, dmin_ref, dmax_ref, valid_ref,
          new_dict_ref, new_dmin_ref, new_dmax_ref, new_valid_ref,
          dec_ref) = refs
         raw_ref = rawdict_ref = new_raw_ref = None
     else:
-        (meta_ref, xs_ref, raw_ref, dict_ref, rawdict_ref, dmin_ref,
-         dmax_ref, valid_ref, new_dict_ref, new_raw_ref, new_dmin_ref,
-         new_dmax_ref, new_valid_ref, dec_ref) = refs
+        (bound_ref, meta_ref, xs_ref, raw_ref, dict_ref, rawdict_ref,
+         dmin_ref, dmax_ref, valid_ref, new_dict_ref, new_raw_ref,
+         new_dmin_ref, new_dmax_ref, new_valid_ref, dec_ref) = refs
     i = pl.program_id(0)
     nprog = pl.num_programs(0)
     n = xs_ref.shape[1]
@@ -154,15 +161,16 @@ def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
     else:
         gate = dvalid
 
-    if error_bound is not None:
+    if eb:
         # carry the raw (stream-order) rows alongside the sorted ones and
         # fold the pointwise-error demotion into the gate: a tile where no
         # entry is within the bound also skips its KS rank work.  Computed
         # in the stored dtype (no f32 cast) so the per-entry max|err| is
-        # exactly what the no-permutation decode reproduces.
+        # exactly what the no-permutation decode reproduces; the bound is
+        # the SMEM operand, already rounded to the stored dtype (exact).
         new_raw_ref[pl.ds(off, tile_d), :] = rawdict_ref[...]
         diff = raw_ref[...] - rawdict_ref[...]  # (tile_d, n)
-        bound = jnp.asarray(error_bound, diff.dtype)
+        bound = bound_ref[0, 0].astype(diff.dtype)
         if chan:
             # per-channel metric choice; pad columns hold inf - inf = NaN
             # and are masked out before the max
@@ -176,7 +184,13 @@ def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
             err_ok = _max_abs_cumsum(diff) <= bound
         else:
             err_ok = jnp.max(jnp.abs(diff), axis=1, keepdims=True) <= bound
+        # demotion accounting: did any entry pass the gate before the
+        # bound, and did any pass it after (accumulated over the tiles)
+        dec_ref[0, DEC_DEMOTED] = jnp.maximum(
+            dec_ref[0, DEC_DEMOTED], jnp.max(gate.astype(jnp.int32)))
         gate = gate & err_ok
+        dec_ref[0, DEC_EB_PASS] = jnp.maximum(
+            dec_ref[0, DEC_EB_PASS], jnp.max(gate.astype(jnp.int32)))
 
     ids = off + jax.lax.broadcasted_iota(jnp.int32, (tile_d, 1), 0)
 
@@ -191,7 +205,13 @@ def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
             cmp_d_le_x = (ds[:, :, None] <= xs[None, :, :]
                           ).astype(jnp.float32)
             cnt_d = jnp.sum(cmp_d_le_x, axis=1)                 # (tile_d, n)
-            f_x_at_x = (col.astype(jnp.float32) + 1.0) * inv_n
+            # F_x at its own points counts the candidate values <= each
+            # one: col + 1 unless values tie (a tied run, e.g. a constant
+            # block, takes the count at its end, as the ECDF does); +inf
+            # pad columns are never <= a real one
+            cnt_xx = jnp.sum((xs[:, None, :] <= xs[:, :, None]
+                              ).astype(jnp.float32), axis=2)    # (1, n)
+            f_x_at_x = cnt_xx * inv_n
             a1 = jnp.abs(f_x_at_x - cnt_d * inv_n)
             if chan:  # zero-fill pad columns (KS >= 0) before the max
                 a1 = jnp.where(col_ok, a1, 0.0)
@@ -230,6 +250,10 @@ def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
         dec_ref[0, DEC_SLOT] = jnp.where(bvalid, jnp.where(is_hit, best, ins), 0)
         dec_ref[0, DEC_OVER] = overwrite.astype(jnp.int32)
         dec_ref[0, DEC_COUNT] = count + do_ins.astype(jnp.int32)
+        if eb:
+            demoted = (bvalid & (dec_ref[0, DEC_DEMOTED] != 0)
+                       & (dec_ref[0, DEC_EB_PASS] == 0))
+            dec_ref[0, DEC_DEMOTED] = demoted.astype(jnp.int32)
 
         @pl.when(do_ins)
         def _insert():
@@ -237,7 +261,7 @@ def _encode_step_kernel(d_crit, rel_tol, use_minmax, use_ks, error_bound,
             new_dmin_ref[pl.ds(ins, 1), :] = xs_ref[:, 0:1]
             new_dmax_ref[pl.ds(ins, 1), :] = xmax.astype(new_dmax_ref.dtype)
             new_valid_ref[pl.ds(ins, 1), :] = jnp.ones((1, 1), jnp.int32)
-            if error_bound is not None:
+            if eb:
                 new_raw_ref[pl.ds(ins, 1), :] = raw_ref[...]
 
 
@@ -247,13 +271,13 @@ def _smem():
 
 @functools.partial(jax.jit, static_argnames=(
     "d_crit", "rel_tol", "use_minmax", "use_ks", "num_dict", "tile_d",
-    "error_bound", "error_cumulative"))
+    "error_cumulative"))
 def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
                        block_valid, *, d_crit: float, rel_tol: float,
                        num_dict: int, use_minmax: bool = True,
                        use_ks: bool = True, tile_d: int = TILE_D,
                        raw=None, raw_blocks=None,
-                       error_bound: float | None = None,
+                       error_bound=None,
                        error_cumulative: bool = False,
                        chan=None):
     """One fused encode step.
@@ -270,8 +294,14 @@ def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
 
     With ``error_bound`` set, ``raw`` (n,) and ``raw_blocks`` (Dp, n) carry
     the stream-order rows, the pointwise max|err| demotion joins the gate,
-    and the return becomes
-    ``(new_sorted, new_dmin, new_dmax, new_valid, new_raw, dec)``.
+    ``dec[DEC_DEMOTED]`` flags a block the bound demoted, and the return
+    becomes ``(new_sorted, new_dmin, new_dmax, new_valid, new_raw, dec)``.
+    The bound is a traced scalar operand, not a compile-time constant:
+    whether it is set (``None`` or not) picks the program, its value does
+    not, so every bound shares one executable per shape.  It is rounded to
+    the stored dtype first (``jnp.asarray(bound, dtype)``, as the
+    comparison always was) and travels in SMEM as f32 (f64 for f64 data,
+    which only the interpreter runs), a widening that is exact.
 
     ``chan`` is the optional (8,) f32 per-channel parameter operand of the
     masked mixed-mode scan (``CHAN_*`` layout: logical width as f32, the
@@ -285,17 +315,29 @@ def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
     check_tile_divisible(num_dp, tile_d, "encode_step_pallas")
     if not 1 <= num_dict <= num_dp:
         raise ValueError(f"num_dict={num_dict} outside [1, Dp={num_dp}]")
-    eb = error_bound is not None
-    if eb and (raw is None or raw_blocks is None):
-        raise ValueError("error_bound requires raw and raw_blocks")
+    if error_bound is not None:
+        if raw is None or raw_blocks is None:
+            raise ValueError("error_bound requires raw and raw_blocks")
+        carry = BoundedCarry(
+            sorted_blocks, raw_blocks, dmin.reshape(num_dp, 1),
+            dmax.reshape(num_dp, 1),
+            valid.astype(jnp.int32).reshape(num_dp, 1),
+            jnp.asarray(count, jnp.int32))
+        c, dec = encode_step_bounded(
+            carry, xs_sorted, raw, block_valid, error_bound, d_crit=d_crit,
+            rel_tol=rel_tol, num_dict=num_dict, use_minmax=use_minmax,
+            use_ks=use_ks, tile_d=tile_d, error_cumulative=error_cumulative,
+            chan=chan)
+        return (c.sorted_blocks, c.dmin.reshape(num_dp),
+                c.dmax.reshape(num_dp), c.valid.reshape(num_dp) != 0,
+                c.raw_blocks, dec)
     grid = (num_dp // tile_d,)
     meta = jnp.stack([jnp.asarray(count, jnp.int32),
                       jnp.asarray(block_valid).astype(jnp.int32)])[None]
     kernel = functools.partial(
         _encode_step_kernel, float(d_crit), float(rel_tol), bool(use_minmax),
-        bool(use_ks), None if error_bound is None else float(error_bound),
-        bool(error_cumulative), int(num_dict), int(tile_d),
-        chan is not None)
+        bool(use_ks), False, bool(error_cumulative), int(num_dict),
+        int(tile_d), chan is not None)
     row = pl.BlockSpec((1, n), lambda i: (0, 0))           # candidate: reused
     tile = pl.BlockSpec((tile_d, n), lambda i: (i, 0))     # streamed dict tile
     col_tile = pl.BlockSpec((tile_d, 1), lambda i: (i, 0))
@@ -314,16 +356,6 @@ def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
     operands = [meta, xs_sorted.reshape(1, n), sorted_blocks,
                 dmin.reshape(num_dp, 1), dmax.reshape(num_dp, 1),
                 valid.astype(jnp.int32).reshape(num_dp, 1)]
-    if eb:
-        # raw candidate after xs, raw dict tile after the sorted tile, raw
-        # carry-out after the sorted carry-out (kernel unpack order)
-        in_specs[2:2] = [row]
-        in_specs[4:4] = [tile]
-        out_specs.insert(1, carry)
-        out_shape.insert(1, jax.ShapeDtypeStruct((num_dp, n),
-                                                 raw_blocks.dtype))
-        operands[2:2] = [raw.reshape(1, n)]
-        operands[4:4] = [raw_blocks]
     if chan is not None:
         # channel-parameter block leads the operand list (kernel unpack)
         in_specs.insert(0, _smem())
@@ -336,11 +368,95 @@ def encode_step_pallas(xs_sorted, sorted_blocks, dmin, dmax, valid, count,
         out_specs=out_specs,
         out_shape=out_shape,
     )(*operands)
-    if eb:
-        new_sorted, new_raw, ndmin, ndmax, nvalid, dec = out
-    else:
-        new_sorted, ndmin, ndmax, nvalid, dec = out
+    new_sorted, ndmin, ndmax, nvalid, dec = out
     outs = (new_sorted, ndmin.reshape(num_dp), ndmax.reshape(num_dp),
             nvalid.reshape(num_dp) != 0)
+    return outs + (dec.reshape(8),)
+
+
+class BoundedCarry(NamedTuple):
+    """The error-bounded step's dictionary carry in the kernel's own
+    layout, so that a scan threads it from step to step with no reshape,
+    cast or copy: ``sorted_blocks``/``raw_blocks`` (Dp, n), ``dmin``/
+    ``dmax`` (Dp, 1), ``valid`` (Dp, 1) int32, ``count`` () int32."""
+
+    sorted_blocks: jax.Array
+    raw_blocks: jax.Array
+    dmin: jax.Array
+    dmax: jax.Array
+    valid: jax.Array
+    count: jax.Array
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "d_crit", "rel_tol", "use_minmax", "use_ks", "num_dict", "tile_d",
+    "error_cumulative"))
+def encode_step_bounded(carry: BoundedCarry, xs_sorted, raw, block_valid,
+                        error_bound, *, d_crit: float, rel_tol: float,
+                        num_dict: int, use_minmax: bool = True,
+                        use_ks: bool = True, tile_d: int = TILE_D,
+                        error_cumulative: bool = False, chan=None):
+    """The error-bounded fused step on a :class:`BoundedCarry`: returns
+    ``(new_carry, dec)`` with ``dec`` (8,) as in
+    :func:`encode_step_pallas`.  The carry-out aliases the carry-in (the
+    kernel writes its carry-out blocks back only after its last grid
+    program has read every input tile), so inside a scan the carry is
+    updated in place.  ``error_bound`` is the traced bound (see
+    :func:`encode_step_pallas`)."""
+    num_dp, n = carry.sorted_blocks.shape
+    check_tile_divisible(num_dp, tile_d, "encode_step_bounded")
+    if not 1 <= num_dict <= num_dp:
+        raise ValueError(f"num_dict={num_dict} outside [1, Dp={num_dp}]")
+    dtype = carry.raw_blocks.dtype
+    meta = jnp.stack([carry.count,
+                      jnp.asarray(block_valid).astype(jnp.int32)])[None]
+    # the bound is rounded to the stored dtype, then widened (exactly) to
+    # the SMEM word
+    sdt = jnp.promote_types(dtype, jnp.float32)
+    bound = jnp.asarray(error_bound, dtype).astype(sdt).reshape(1, 1)
+    kernel = functools.partial(
+        _encode_step_kernel, float(d_crit), float(rel_tol), bool(use_minmax),
+        bool(use_ks), True, bool(error_cumulative), int(num_dict),
+        int(tile_d), chan is not None)
+    row = pl.BlockSpec((1, n), lambda i: (0, 0))           # candidate: reused
+    tile = pl.BlockSpec((tile_d, n), lambda i: (i, 0))     # streamed dict tile
+    col_tile = pl.BlockSpec((tile_d, 1), lambda i: (i, 0))
+    # constant index maps: carry-out lives in VMEM across the grid
+    full = pl.BlockSpec((num_dp, n), lambda i: (0, 0))
+    col_full = pl.BlockSpec((num_dp, 1), lambda i: (0, 0))
+    # kernel unpack order: [chan], bound, meta, xs, raw row, sorted tile,
+    # raw tile, dmin, dmax, valid
+    in_specs = [_smem(), _smem(), row, row, tile, tile, col_tile, col_tile,
+                col_tile]
+    operands = [bound, meta, xs_sorted.reshape(1, n), raw.reshape(1, n),
+                carry.sorted_blocks, carry.raw_blocks, carry.dmin,
+                carry.dmax, carry.valid]
+    out_specs = [full, full, col_full, col_full, col_full, _smem()]
+    out_shape = [
+        jax.ShapeDtypeStruct((num_dp, n), carry.sorted_blocks.dtype),
+        jax.ShapeDtypeStruct((num_dp, n), dtype),
+        jax.ShapeDtypeStruct((num_dp, 1), carry.dmin.dtype),
+        jax.ShapeDtypeStruct((num_dp, 1), carry.dmax.dtype),
+        jax.ShapeDtypeStruct((num_dp, 1), jnp.int32),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32),
+    ]
+    lead = 0
+    if chan is not None:
+        in_specs.insert(0, _smem())
+        operands.insert(0, chan.reshape(1, -1))
+        lead = 1
+    # the five carry operands (after the bound, meta and the two rows)
+    # become the first five outputs
+    aliases = {lead + 4 + k: k for k in range(5)}
+    new_sorted, new_raw, ndmin, ndmax, nvalid, dec = pallas_call(
+        kernel,
+        name="encode_step",
+        grid=(num_dp // tile_d,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+    )(*operands)
     dec = dec.reshape(8)
-    return outs + ((new_raw,) if eb else ()) + (dec,)
+    return BoundedCarry(new_sorted, new_raw, ndmin, ndmax, nvalid,
+                        dec[DEC_COUNT]), dec

@@ -19,7 +19,9 @@ def dict_match_ref(xs_sorted, dict_blocks, dmin, dmax, rel_tol):
     inv_n = 1.0 / n
 
     cnt_d = jnp.sum(ds[:, :, None] <= xs[None, None, :], axis=1)  # (D, n)
-    f_x_at_x = (jnp.arange(1, n + 1, dtype=jnp.float32)) * inv_n
+    # F_x at its own points: count of candidate values <= each (ties)
+    cnt_xx = jnp.sum(xs[None, :] <= xs[:, None], axis=1)         # (n,)
+    f_x_at_x = cnt_xx.astype(jnp.float32) * inv_n
     d1 = jnp.max(jnp.abs(f_x_at_x[None, :] - cnt_d * inv_n), axis=1)
 
     cnt_x = jnp.sum(xs[None, None, :] <= ds[:, :, None], axis=2)  # (D, n)

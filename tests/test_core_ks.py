@@ -25,6 +25,40 @@ def test_statistic_matches_scipy(n1, n2):
         assert np.isclose(ks_statistic_np(x, y), ref, atol=1e-12)
 
 
+@pytest.mark.parametrize("case", ["constant", "quantized", "one_tie"])
+def test_tied_samples_match_scipy_on_every_matcher(case):
+    """Tied values (a constant block, quantized data, one repeated value)
+    take the ECDF's count at the end of their run in every device
+    matcher, as in scipy and the numpy oracle: a constant block against
+    itself reads 0, not (n - 1) / n."""
+    import jax.numpy as jnp
+
+    from repro.core.ks import ks_statistic_many
+    from repro.kernels.ops import dict_match
+    from repro.kernels.ref import dict_match_ref
+
+    rng = np.random.default_rng(3)
+    n = 31
+    if case == "constant":
+        x, rows = np.zeros(n), [np.zeros(n), np.full(n, 1.0),
+                                np.r_[np.zeros(20), np.ones(11)]]
+    elif case == "quantized":
+        x = np.round(rng.normal(size=n))
+        rows = [np.round(rng.normal(size=n)) for _ in range(8)]
+    else:
+        x = rng.normal(size=n)
+        x[5] = x[9]
+        rows = [rng.normal(size=n) for _ in range(7)] + [x.copy()]
+    xs = jnp.sort(jnp.asarray(x, jnp.float32))
+    ds = jnp.sort(jnp.asarray(np.stack(rows), jnp.float32), axis=1)
+    want = [scipy.stats.ks_2samp(x, r).statistic for r in rows]
+    mn, mx = ds[:, 0], ds[:, -1]
+    for got in (ks_statistic_many(xs, ds), dict_match(xs, ds, mn, mx, 0.5)[0],
+                dict_match_ref(xs, ds, mn, mx, 0.5)[0]):
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-6)
+    assert [ks_statistic_np(x, r) for r in rows] == pytest.approx(want)
+
+
 def test_pvalue_matches_kolmogorov_sf():
     for n in [8, 16, 64, 256]:
         for d in [0.05, 0.1, 0.3, 0.7]:

@@ -55,6 +55,27 @@ def test_fused_dtype_ring(dtype):
     _assert_same_decisions(ref, fused)
 
 
+@pytest.mark.parametrize("matcher", [None, "ops", "fused"])
+def test_constant_and_tied_blocks_decide_as_the_host_oracle(matcher):
+    """Blocks with tied values -- runs of zeros, a constant level,
+    quantized samples -- make the host walk's decisions on every device
+    matcher (a constant block hits an equal stored one)."""
+    from repro.core.npref import encode_decisions_np
+
+    rng = np.random.default_rng(4)
+    n = 15
+    blocks = np.concatenate([
+        np.zeros((6, n)), np.full((3, n), 2.0),
+        np.round(rng.normal(size=(12, n))),
+        np.r_[np.zeros(8), rng.uniform(0.1, 1.0, 7)][None].repeat(4, 0),
+    ]).astype(np.float32)
+    kw = dict(num_dict=9, d_crit=(int(0.4 * n) + 0.5) / n, rel_tol=0.5)
+    want = encode_decisions_np(blocks, **kw)
+    got = encode_decisions(jnp.asarray(blocks), matcher=matcher, **kw)
+    _assert_same_decisions(want, got)
+    assert np.asarray(got[0])[:6].sum() == 5     # zeros hit the first zero
+
+
 def test_fused_matches_ops():
     """Fused and composed pallas paths share the same kernel arithmetic.
 

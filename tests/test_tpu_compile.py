@@ -4,7 +4,8 @@ Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 rank-1 blocks that are not 128-lane multiples, scalar stores to VMEM, a
 kernel that outgrows scoped VMEM.  These tests compile each kernel at the
 paper's Table I widths (D=255 padded to 256; n=32 for magnitude blocks,
-n=111 for B=112 delta payloads) for a chip that is described, not attached,
+n=111 for B=112 delta payloads, n=31 for B=32 residual payloads of field
+snapshots) for a chip that is described, not attached,
 and check that the compiled program holds the kernel (``tpu_custom_call``),
 under its stable name, and not the interpreter.  Each kernel also
 compiles vmapped over a channel axis, the shape batched sessions, the
@@ -32,7 +33,7 @@ from repro.kernels.seq_cumsum import seq_cumsum_pallas
 
 NUM_DICT = 255
 DP = 256          # NUM_DICT padded to a tile multiple
-WIDTHS = (32, 111)
+WIDTHS = (32, 111, 31)
 
 
 @pytest.fixture(scope="module")
@@ -86,13 +87,15 @@ def test_encode_step_compiles(one_chip, variant, n, tile_d, channels):
         fn = functools.partial(encode_step_pallas, **kw)
     elif variant == "error_bound":
         # delta payloads bound the cumulative error (the in-kernel column
-        # walk); the plain bound is the same kernel minus that walk
+        # walk); the plain bound is the same kernel minus that walk.  The
+        # bound is a traced operand (SMEM), batched with the channels here
         def fn(*a):
-            *carry, raw, raw_blocks = a
+            *carry, raw, raw_blocks, bound = a
             return encode_step_pallas(*carry, raw=raw, raw_blocks=raw_blocks,
-                                      error_bound=0.1,
+                                      error_bound=bound,
                                       error_cumulative=n == 111, **kw)
-        args += [s((n,), jnp.float32), s((DP, n), jnp.float32)]
+        args += [s((n,), jnp.float32), s((DP, n), jnp.float32),
+                 s((), jnp.float32)]
     else:
         def fn(*a):
             *carry, chan = a
@@ -118,6 +121,33 @@ def test_direct_batched_scan_compiles(one_chip, nb, n):
     text = _encode_scan(True).lower(
         carry, blocks, None, d_crit=0.3, rel_tol=0.5, use_minmax=True,
         use_ks=True, matcher=matcher).compile().as_text()
+    assert re.match(r"HloModule jit_scan\b", text), text[:80]
+    _named_kernel(text, "encode_step")
+
+
+@pytest.mark.parametrize("channels", [1, 4])
+@pytest.mark.parametrize("nb", [7812, 7813])
+def test_direct_batched_error_bounded_scan_compiles(one_chip, nb, channels):
+    """The error-bounded direct scan at a field snapshot's level feeds
+    (250,000 samples: 7,812 then 7,813 residual payloads of 31) with the
+    bound a traced scalar shared by the lanes (unbatched under the channel
+    vmap), one ``jit_scan`` program holding the fused kernel; its third
+    output is the per-lane demotion count."""
+    n = 31
+    carry = jax.eval_shape(functools.partial(
+        init_state, NUM_DICT, n, channels=channels, raw=True))
+    carry = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), carry)
+    blocks = jax.ShapeDtypeStruct((channels, nb, n), jnp.float32,
+                                  sharding=one_chip)
+    bound = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    matcher = resolve_matcher("fused", num_dict=NUM_DICT, n=n,
+                              dtype=jnp.float32)
+    lowered = _encode_scan(True).lower(
+        carry, blocks, None, d_crit=0.3, rel_tol=0.5, use_minmax=True,
+        use_ks=True, matcher=matcher, error_bound=bound)
+    assert lowered.out_info[2].shape == (channels,)
+    text = lowered.compile().as_text()
     assert re.match(r"HloModule jit_scan\b", text), text[:80]
     _named_kernel(text, "encode_step")
 
